@@ -233,6 +233,8 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: reduced matrix on tiny budgets")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         os.environ.setdefault("REPRO_BENCH_SCALE", "0.25")
     fast = (args.fast or args.smoke

@@ -171,38 +171,44 @@ def calibration_params(w: jnp.ndarray, bits: int = 8) -> AffineParams:
 # ---------------------------------------------------------------------------
 
 def pack_int4(codes: jnp.ndarray) -> jnp.ndarray:
-    """Pack signed int4 codes (values in [-8, 7], stored int8) pairwise.
+    """Pack signed int4 codes (values in [-8, 7], stored int8) two per byte.
 
-    Packs along axis 0 (the GEMM contraction axis): rows ``2i`` go to the
-    low nibble, rows ``2i+1`` to the high nibble of one int8 byte —
-    ``(K, N) -> (ceil(K/2), N)``.  An odd K is zero-padded; consumers mask
-    rows ``>= K`` (zero codes are already masked out of the kernels'
-    zero-point corrections by the true-K contract).
+    Packs along axis 0 (the GEMM contraction axis) by halves: with
+    ``h = ceil(K/2)``, byte row ``i`` holds code row ``i`` in its low
+    nibble and code row ``h + i`` in its high nibble —
+    ``(K, N) -> (h, N)``.  An odd K zero-pads the last high nibble.  Each
+    nibble plane is a contiguous K-slice, so a GEMM contracts ``x[:, :h]``
+    against the low nibbles and ``x[:, h:]`` against the high ones with no
+    interleaving reshape (``int4_halves``).
     """
     k = codes.shape[0]
+    h = (k + 1) // 2
     if k % 2:
         pad = [(0, 1)] + [(0, 0)] * (codes.ndim - 1)
         codes = jnp.pad(codes, pad)
-    lo = codes[0::2].astype(jnp.uint8) & 0xF
-    hi = codes[1::2].astype(jnp.uint8) & 0xF
+    lo = codes[:h].astype(jnp.uint8) & 0xF
+    hi = codes[h:].astype(jnp.uint8) & 0xF
     # same-width bitcast, not a value convert: 0x80..0xFF must become the
     # negative byte patterns, which int astype leaves implementation-defined
     return (lo | (hi << 4)).view(jnp.int8)
 
 
-def unpack_int4(packed: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Inverse of ``pack_int4``: ``(ceil(K/2), N) -> (K, N)`` int8 codes.
+def int4_halves(packed: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(low, high)`` nibble planes of ``pack_int4`` bytes as int32 codes.
 
-    Sign-extends each nibble via a left-then-arithmetic-right shift pair —
-    pure jnp, so it runs unchanged inside Pallas kernels (the in-kernel
-    unpack of the W4A8 GEMMs) and in the ref oracles.
+    Sign-extends each nibble with a left-then-arithmetic-right shift pair
+    on int32 — the TPU compiler has no shifts on int8 vectors, so this is
+    the form the in-kernel unpack of the W4A8 GEMMs uses, and the one the
+    oracles use too (identical codes on every backend).
     """
-    lo = packed.astype(jnp.int8) << 4
-    lo = lo >> 4                           # arithmetic shift: sign-extended
-    hi = packed.astype(jnp.int8) >> 4
-    both = jnp.stack([lo, hi], axis=1)     # (Kp, 2, ...)
-    out = both.reshape((-1,) + packed.shape[1:])
-    return out[:k]
+    v = packed.astype(jnp.int32)
+    return (v << 28) >> 28, (v << 24) >> 28
+
+
+def unpack_int4(packed: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Inverse of ``pack_int4``: ``(ceil(K/2), N) -> (K, N)`` int8 codes."""
+    lo, hi = int4_halves(packed)
+    return jnp.concatenate([lo, hi], axis=0)[:k].astype(jnp.int8)
 
 
 def quantize_symmetric(x: jnp.ndarray, axis: int = -1

@@ -67,17 +67,33 @@ jax.tree_util.register_pytree_node(
 
 
 def _layer_forward(h: jnp.ndarray, w: jnp.ndarray, col_scale, col_zero,
-                   bias, x_delta, x_zero, k: int) -> jnp.ndarray:
-    """int32 GEMM + zero-point correction + dequant epilogue for one layer.
+                   bias, x_delta, x_zero, bits: int, k: int) -> jnp.ndarray:
+    """int8 GEMM + zero-point correction + dequant epilogue for one layer.
 
-    ``h`` is (bm, k) int32 codes, ``w`` (k, n) int32 codes; returns the
-    fp32 (bm, n) pre-activation.  Float op order matches
-    ``ref.int8_matmul_ref`` exactly (the bitwise-anchor contract).
+    ``h`` is (bm, k) int8 codes; ``w`` the layer's (k, n) int8 codes, or
+    the (ceil(k/2), n) ``pack_int4`` bytes when ``bits <= 4`` — each
+    nibble plane then contracts against its own contiguous K half of
+    ``h``.  Returns the fp32 (bm, n) pre-activation.  Float op order
+    matches ``ref.int8_matmul_ref`` exactly (the bitwise-anchor contract).
     """
-    acc = jax.lax.dot_general(h, w, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    sum_h = jnp.sum(h, axis=1, keepdims=True)            # (bm, 1)
-    sum_w = jnp.sum(w, axis=0, keepdims=True)            # (1, n)
+    if bits <= 4:
+        kh = (k + 1) // 2
+        lo, hi = affine.int4_halves(w)
+        parts = [(h[:, :kh], lo)]
+        if k > kh:
+            parts.append((h[:, kh:], hi[:k - kh]))
+    else:
+        parts = [(h, w)]
+    acc = sum_h = sum_w = 0
+    for hp, wp in parts:
+        # int8 codes straight into the MXU, int32 accumulation
+        acc = acc + jax.lax.dot_general(
+            hp, wp.astype(jnp.int8), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        sum_h = sum_h + jnp.sum(hp.astype(jnp.int32), axis=1,
+                                keepdims=True)           # (bm, 1)
+        sum_w = sum_w + jnp.sum(wp.astype(jnp.int32), axis=0,
+                                keepdims=True)           # (1, n)
     xz = x_zero.astype(jnp.int32)
     wz = col_zero.astype(jnp.int32)                      # (1, n)
     corr = acc - xz * sum_w - wz * sum_h + k * xz * wz
@@ -90,24 +106,21 @@ def _fused_qmlp_kernel(*refs, metas: Tuple[Tuple[int, int], ...]):
     bias, x_delta, x_zero), then the output; ``metas`` = static
     ``(bits, k)`` per layer."""
     x_ref, o_ref = refs[0], refs[-1]
-    h = x_ref[...].astype(jnp.int32)
+    h = x_ref[...]
     n_layers = len(metas)
     for i, (bits, k) in enumerate(metas):
         c_ref, ws_ref, wz_ref, b_ref, xd_ref, xz_ref = refs[1 + 6 * i:
                                                             7 + 6 * i]
-        w = c_ref[...]
-        if bits <= 4:
-            w = affine.unpack_int4(w, k)                 # in-kernel unpack
-        y = _layer_forward(h, w.astype(jnp.int32), ws_ref[0, :][None, :],
+        y = _layer_forward(h, c_ref[...], ws_ref[0, :][None, :],
                            wz_ref[0, :][None, :], b_ref[0, :][None, :],
-                           xd_ref[0, 0], xz_ref[0, 0], k)
+                           xd_ref[0, 0], xz_ref[0, 0], bits, k)
         if i + 1 < n_layers:
             # fused epilogue: ReLU + static requant — the activation stays
-            # int8-coded (held int32 for the next MXU feed) in VMEM
+            # int8-coded in VMEM for the next MXU feed
             y = jnp.maximum(y, 0.0)
             nxd_ref, nxz_ref = refs[1 + 6 * (i + 1) + 4:1 + 6 * (i + 1) + 6]
             q = jnp.round(y / nxd_ref[0, 0]) + nxz_ref[0, 0]
-            h = jnp.clip(q, -128.0, 127.0).astype(jnp.int32)
+            h = jnp.clip(q, -128.0, 127.0).astype(jnp.int8)
         else:
             o_ref[...] = y.astype(o_ref.dtype)
 

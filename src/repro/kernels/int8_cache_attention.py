@@ -44,7 +44,7 @@ def _kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     # mask: valid slots [0, pos], ring-window if any
-    pos = pos_ref[0]
+    pos = pos_ref[0, 0]
     t_idx = tj * block_t + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_t), 1)
     valid = (t_idx <= pos) & (t_idx < t_total)
@@ -101,4 +101,6 @@ def int8_cache_decode_attention(q: jnp.ndarray, k_codes: jnp.ndarray,
         ],
         interpret=interpret,
     )(q, k_codes, k_scale, v_codes, v_scale,
-      jnp.asarray(pos, jnp.int32).reshape(1))
+      # (1, 1), not (1,): a vmapped (ragged) pos then batches to
+      # (B, 1, 1), whose block still spans the array's last two dims
+      jnp.asarray(pos, jnp.int32).reshape(1, 1))

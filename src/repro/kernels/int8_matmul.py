@@ -9,6 +9,8 @@ in the epilogue — one fused kernel instead of dequantize-then-matmul.
 
 Layout: x_q (M,K) int8 with per-tensor scale/zero; w_q (K,N) int8 with
 per-output-channel (N,) scale/zero — the paper's per-tensor/per-axis split.
+W4A8 weights arrive byte-packed by K halves and are unpacked in-kernel with
+int32 shifts (the chip has no int8 vector shifts).
 
 Grid is (M/bm, N/bn, K/bk) with K innermost; the int32 accumulator and the
 two zero-point correction sums live in VMEM scratch across the K iterations.
@@ -25,9 +27,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import affine
 
 
-def _int8_matmul_kernel(x_ref, w_ref, xs_ref, xz_ref, ws_ref, wz_ref,
-                        o_ref, acc_ref, sumx_ref, sumw_ref, *, n_k: int,
-                        k_total: int, w_bits: int = 8):
+def _int8_matmul_kernel(*refs, n_k: int, k_total: int, k_len: int,
+                        w_bits: int):
+    """``refs`` = the x K-part(s), w, xs, xz, ws, wz, out, then the acc /
+    sum_x / sum_w scratch.  W8A8 has one x part; W4A8 has two — the K
+    halves that the low and high nibble planes of ``w`` contract with.
+    ``k_len`` is the K extent of each part (its last block may overhang
+    the array), ``k_total`` the true reduction length."""
+    n_x = 2 if w_bits <= 4 else 1
+    x_refs, w_ref = refs[:n_x], refs[n_x]
+    (xs_ref, xz_ref, ws_ref, wz_ref, o_ref,
+     acc_ref, sumx_ref, sumw_ref) = refs[n_x + 1:]
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
@@ -36,27 +46,33 @@ def _int8_matmul_kernel(x_ref, w_ref, xs_ref, xz_ref, ws_ref, wz_ref,
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
         sumw_ref[...] = jnp.zeros_like(sumw_ref)
 
-    x = x_ref[...].astype(jnp.int32)   # (bm, bk) — widened for CPU interpret;
-    w = w_ref[...]                     # on TPU the MXU consumes int8 directly.
     if w_bits <= 4:
-        # sub-8-bit weights arrive packed two-per-byte along K: the block
-        # holds bk/2 packed rows; unpack in-kernel.  Garbage nibbles (the
-        # pad byte of an odd K and OOB block reads) only occupy rows
-        # >= k_total, which the k_valid mask below zeroes anyway.
-        w = affine.unpack_int4(w, x_ref.shape[1])
-    w = w.astype(jnp.int32)
-    # Zero the padded K tail of the last block (pallas pads OOB reads with an
-    # unspecified value; zero codes are the additive identity for acc AND the
-    # zero-point correction sums).
-    bk = x_ref.shape[1]
-    k_pos = k_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    k_valid = k_pos < k_total
-    x = jnp.where(k_valid, x, 0)
-    w = jnp.where(k_valid.T, w, 0)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    sumx_ref[...] += jnp.sum(x, axis=1, keepdims=True)       # (bm, 1)
-    sumw_ref[...] += jnp.sum(w, axis=0, keepdims=True)       # (1, bn)
+        w_parts = affine.int4_halves(w_ref[...])        # int32 nibbles
+    else:
+        w_parts = (w_ref[...],)
+    bk = w_ref.shape[0]
+    for x_ref, w in zip(x_refs, w_parts):
+        x = x_ref[...]                                   # (bm, bk) int8
+        if k_len % bk:
+            # the last K block overhangs the array and pallas fills the
+            # overhang with unspecified values: zero it (zero codes are
+            # the additive identity for acc AND the zero-point sums).
+            # Each operand builds its own mask from its own iota.
+            k0 = k_idx * bk
+            x = jnp.where(
+                k0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+                < k_len, x, 0)
+            w = jnp.where(
+                k0 + jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+                < k_len, w, 0)
+        # int8 codes straight into the MXU, int32 accumulation
+        acc_ref[...] += jax.lax.dot_general(
+            x, w.astype(jnp.int8), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        sumx_ref[...] += jnp.sum(x.astype(jnp.int32), axis=1,
+                                 keepdims=True)              # (bm, 1)
+        sumw_ref[...] += jnp.sum(w.astype(jnp.int32), axis=0,
+                                 keepdims=True)              # (1, bn)
 
     @pl.when(k_idx == n_k - 1)
     def _epilogue():
@@ -83,24 +99,30 @@ def int8_matmul_pallas(x_q: jnp.ndarray, w_q: jnp.ndarray,
     """Dequantized (M,N) product of int8 (M,K) x (K,N).
 
     ``w_bits <= 4``: ``w_q`` is ``(ceil(K/2), N)`` with two int4 codes per
-    byte along K (``core.affine.pack_int4``), unpacked in-kernel; K comes
-    from ``x_q``.
+    byte along K (``core.affine.pack_int4``: low nibbles hold the first K
+    half, high nibbles the second), unpacked in-kernel; K comes from
+    ``x_q``, whose two halves enter the kernel as separate operands.
+
+    A dimension no larger than its block is taken whole (no block is ever
+    wider than the array); a longer K is tiled by ``block_k``, which must
+    then be a multiple of 128 on the chip.
     """
     m, k = x_q.shape
     if w_bits <= 4:
-        assert w_q.shape[0] == (k + 1) // 2, (w_q.shape, k)
-        n = w_q.shape[1]
-        # even K block so each maps to an integral number of packed rows
-        bk = min(block_k, k + (k % 2))
-        bk += bk % 2
-        w_rows = bk // 2
+        k_len = (k + 1) // 2
+        # the second half is one column short for odd K: pad it with zero
+        # codes, which meet the zero-padded high nibble of pack_int4
+        x_parts = (x_q[:, :k_len],
+                   jnp.pad(x_q[:, k_len:], ((0, 0), (0, 2 * k_len - k))))
     else:
-        k2, n = w_q.shape
-        assert k == k2
-        bk = min(block_k, k)
-        w_rows = bk
-    bm, bn = min(block_m, m), min(block_n, n)
-    n_k = pl.cdiv(k, bk)
+        k_len = k
+        x_parts = (x_q,)
+    if w_q.shape[0] != k_len:
+        raise ValueError(f"w_bits={w_bits} expects {k_len} weight rows for "
+                         f"K={k}, got {w_q.shape}")
+    n = w_q.shape[1]
+    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k_len)
+    n_k = pl.cdiv(k_len, bk)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
 
     xs = jnp.asarray(x_scale, jnp.float32).reshape(1, 1)
@@ -108,13 +130,13 @@ def int8_matmul_pallas(x_q: jnp.ndarray, w_q: jnp.ndarray,
     ws = jnp.asarray(w_scale, jnp.float32).reshape(1, n)
     wz = jnp.asarray(w_zero, jnp.float32).reshape(1, n)
 
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
     return pl.pallas_call(
         functools.partial(_int8_matmul_kernel, n_k=n_k, k_total=k,
-                          w_bits=w_bits),
+                          k_len=k_len, w_bits=w_bits),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((w_rows, bn), lambda i, j, kk: (kk, j)),
+        in_specs=[x_spec] * len(x_parts) + [
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
@@ -130,4 +152,4 @@ def int8_matmul_pallas(x_q: jnp.ndarray, w_q: jnp.ndarray,
             pltpu.VMEM((1, bn), jnp.int32),
         ],
         interpret=interpret,
-    )(x_q, w_q, xs, xz, ws, wz)
+    )(*x_parts, w_q, xs, xz, ws, wz)

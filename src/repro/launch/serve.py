@@ -266,6 +266,8 @@ def main(argv=None) -> int:
                          "--ckpt-dir before serving")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.rl_env:
         return _serve_policy(args)
 
@@ -323,7 +325,8 @@ def main(argv=None) -> int:
     dt = time.time() - t0
     n_gen = args.batch * len(out_tokens)
     print(f"[serve] generated {len(out_tokens)} tokens x {args.batch} seqs "
-          f"in {dt:.2f}s ({n_gen / dt:.1f} tok/s on CPU)")
+          f"in {dt:.2f}s ({n_gen / dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform})")
     print("        first sequence:", [int(t[0]) for t in out_tokens][:16])
     return 0
 

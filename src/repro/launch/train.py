@@ -65,6 +65,8 @@ def main(argv=None) -> int:
                          "escalations after retries exhaust")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "rl":
         return run_rl(args)
     return run_lm(args)

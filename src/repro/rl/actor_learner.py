@@ -72,11 +72,10 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.rl import actorq, common, ddpg, dqn
 from repro.rl import buffer as rb
-from repro.rl.distributed import shard_map_compat
 from repro.rl.env import Env, batched_env, rollout
 
 ALGOS = ("dqn", "ddpg")
@@ -441,25 +440,33 @@ def remint_cache(state: ActorLearnerState, actor_backend: str, *,
                                    backend=kernel_backend)
 
 
-def _state_specs(state: ActorLearnerState, axis: str):
-    """Partition specs for the state pytree: replay + divergence live on the
+def mesh_specs(tree, axis: str = "actor"):
+    """Partition specs for a topology carry (``ActorLearnerState`` or a bare
+    learner ``TrainState``): replay (and divergence) leaves live on the
     actor axis, everything else (learner params/opt, actor copy + cache)
-    replicated.
+    is replicated.  The programs' ``shard_map`` specs and ``place`` both
+    come from here, so a placed carry is exactly what the programs take.
     """
     def one(path, leaf):
         names = {getattr(entry, "name", None) for entry in path}
         sharded = "replay" in names or "divergence" in names
         return P(axis) if sharded else P()
-    return jax.tree_util.tree_map_with_path(one, state)
+    return jax.tree_util.tree_map_with_path(one, tree)
 
 
-def _learner_specs(learner: common.TrainState, axis: str):
-    """Partition specs for a bare learner ``TrainState``: the (read-slot)
-    replay is sharded over the actor axis, everything else replicated."""
-    def one(path, leaf):
-        names = {getattr(entry, "name", None) for entry in path}
-        return P(axis) if "replay" in names else P()
-    return jax.tree_util.tree_map_with_path(one, learner)
+def place(tree, mesh, specs):
+    """Commit ``tree`` to ``mesh`` under ``specs`` (a matching pytree, or a
+    prefix of one, of ``PartitionSpec``).
+
+    Call once on every carry before the first sharded program: arrays made
+    off the mesh sit on one device, and a donated input whose sharding
+    differs from its output's cannot alias it.  Placed arrays keep their
+    shardings through the programs, so later calls move nothing.
+    """
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda s: isinstance(s, P))
+    return jax.device_put(tree, shardings)
 
 
 def make_actor_learner(algo: str, env: Env, net, cfg,
@@ -560,7 +567,7 @@ def make_actor_learner(algo: str, env: Env, net, cfg,
                 calib_obs = None
                 if cfg.calib_batch:
                     # the cache is carried replicated over the actor axis
-                    # (P() in _state_specs): on a mesh, gather the
+                    # (P() in mesh_specs): on a mesh, gather the
                     # calibration batch so every device derives identical
                     # scales (collective only inside the sync branch)
                     calib_obs = obs if axis_name is None else \
@@ -606,13 +613,14 @@ def make_actor_learner(algo: str, env: Env, net, cfg,
     else:
         @jax.jit
         def iteration(state, env_state, obs, key):
-            specs = _state_specs(state, axis)
+            specs = mesh_specs(state, axis)
             metric_specs = {"loss": P(), "reward": P(),
                             "divergence": P(axis), "synced": P()}
-            sharded = shard_map_compat(
-                functools.partial(core, axis_name=axis), mesh,
+            sharded = jax.shard_map(
+                functools.partial(core, axis_name=axis), mesh=mesh,
                 in_specs=(specs, P(axis), P(axis), P()),
-                out_specs=(specs, P(axis), P(axis), metric_specs))
+                out_specs=(specs, P(axis), P(axis), metric_specs),
+                check_vma=False)
             return sharded(state, env_state, obs, key)
 
     return iteration, parts.act_fn, benv_global
@@ -640,9 +648,12 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
     edges are the host-level slot swap and the param snapshot at sync
     points.  ``make_snapshot`` packs the int8 cache (the only repack per
     sync) and, being a plain jit, returns fresh buffers that never alias
-    the donated learner state.  With ``mesh``, both programs are
-    ``shard_map``-ped over the actor axis (learner grads pmean-averaged;
-    the slots' shard axis partitioned) as two separate XLA executables.
+    the donated learner state.  ``divergence(learner, snap, obs)`` is the
+    per-sync ``(num_actors,)`` mean-abs gap of a fresh snapshot's
+    behaviour head against the live learner head.  With ``mesh``, every
+    program is ``shard_map``-ped over the actor axis (learner grads
+    pmean-averaged; the slots' shard axis partitioned; the snapshot
+    replicated) as separate XLA executables.
     """
     use_per = rb.use_prioritized(cfg.replay, cfg.priority_exponent)
     n, n_dev = _validate(algo, cfg, al, mesh, axis)
@@ -664,17 +675,12 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
     to_shards = _make_to_shards(local_actors, envs_per_actor)
     add_sharded = rb.per_add_sharded if use_per else rb.replay_add_sharded
 
-    @jax.jit
-    def make_snapshot(learner: common.TrainState,
-                      obs=None) -> ActorSnapshot:
-        """Param push: mint the actors' next (packed) snapshot.
-
-        ``obs`` — the actors' current observations — is only consumed
-        under ``calib_batch > 0``, where each push also recalibrates the
-        cache's static activation scales (the PR-4 repack path carrying
-        the PR-5 static-requant contract); the driver passes it
-        unconditionally, the equivalence-anchor cadence is unchanged.
-        """
+    def snapshot_core(learner: common.TrainState, obs,
+                      axis_name) -> ActorSnapshot:
+        """Param push: mint the actors' next (packed) snapshot.  ``obs``
+        (the actors' current observations) is consumed only under
+        ``calib_batch > 0``, where each push also recalibrates the cache's
+        static activation scales."""
         cache = ()
         if int8:
             calib_obs = None
@@ -684,6 +690,12 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
                         "calib_batch > 0 needs the actors' observations "
                         "at every snapshot — pass make_snapshot(learner, "
                         "obs)")
+                if axis_name is not None:
+                    # the snapshot is replicated: every device calibrates
+                    # on the same gathered batch, the one the unsharded
+                    # program would take
+                    obs = jax.lax.all_gather(obs, axis_name, axis=0,
+                                             tiled=True)
                 calib_obs = actorq.calib_slice(obs, cfg.calib_batch)
             cache = actorq.make_actor_cache(
                 learner.params, cfg.actor_backend, calib_obs=calib_obs,
@@ -737,6 +749,12 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
             loss = jax.lax.pmean(loss, axis_name)
         return learner, {"loss": loss}
 
+    _div = _make_divergence(parts, int8, local_actors, envs_per_actor,
+                            obs_shape)
+
+    def divergence_core(learner, snap, obs):
+        return _div(learner, snap.params, snap.cache, obs)
+
     if mesh is None:
         @functools.partial(jax.jit, static_argnames=("n_chunks",),
                            donate_argnums=(1, 2, 3))
@@ -748,38 +766,54 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
                            donate_argnums=(0,))
         def learner_chunk(learner, key, *, n_updates):
             return learner_core(learner, key, n_updates, None)
+
+        @jax.jit
+        def make_snapshot(learner, obs=None):
+            return snapshot_core(learner, obs, None)
+
+        divergence = jax.jit(divergence_core)
     else:
+        # every program runs inside a shard_map: the Pallas kernels of the
+        # quantized actor cannot be partitioned by the compiler
         @functools.partial(jax.jit, static_argnames=("n_chunks",),
                            donate_argnums=(1, 2, 3))
         def actor_chunk(snap, env_state, obs, wbuf, key, *, n_chunks):
-            sharded = shard_map_compat(
+            sharded = jax.shard_map(
                 functools.partial(actor_core, n_chunks=n_chunks,
                                   axis_name=axis),
-                mesh,
+                mesh=mesh,
                 in_specs=(P(), P(axis), P(axis), P(axis), P()),
-                out_specs=(P(axis), P(axis), P(axis), {"reward": P()}))
+                out_specs=(P(axis), P(axis), P(axis), {"reward": P()}),
+                check_vma=False)
             return sharded(snap, env_state, obs, wbuf, key)
 
         @functools.partial(jax.jit, static_argnames=("n_updates",),
                            donate_argnums=(0,))
         def learner_chunk(learner, key, *, n_updates):
-            specs = _learner_specs(learner, axis)
-            sharded = shard_map_compat(
+            specs = mesh_specs(learner, axis)
+            sharded = jax.shard_map(
                 functools.partial(learner_core, n_updates=n_updates,
                                   axis_name=axis),
-                mesh,
+                mesh=mesh,
                 in_specs=(specs, P()),
-                out_specs=(specs, {"loss": P()}))
+                out_specs=(specs, {"loss": P()}), check_vma=False)
             return sharded(learner, key)
 
-    _div = _make_divergence(parts, int8, n, envs_per_actor, obs_shape)
+        @jax.jit
+        def make_snapshot(learner, obs=None):
+            sharded = jax.shard_map(
+                functools.partial(snapshot_core, axis_name=axis),
+                mesh=mesh, in_specs=(mesh_specs(learner, axis), P(axis)),
+                out_specs=P(), check_vma=False)
+            return sharded(learner, obs)
 
-    @jax.jit
-    def divergence(learner, snap: ActorSnapshot, obs):
-        """(num_actors,) mean-abs behaviour-head gap of a fresh snapshot
-        vs the live learner head — the per-sync divergence record (pure
-        int8-vs-fp32 quantization gap right after a push)."""
-        return _div(learner, snap.params, snap.cache, obs)
+        @jax.jit
+        def divergence(learner, snap, obs):
+            sharded = jax.shard_map(
+                divergence_core, mesh=mesh,
+                in_specs=(mesh_specs(learner, axis), P(), P(axis)),
+                out_specs=P(axis), check_vma=False)
+            return sharded(learner, snap, obs)
 
     return AsyncPrograms(actor_chunk=actor_chunk,
                          learner_chunk=learner_chunk,

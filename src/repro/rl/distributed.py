@@ -31,23 +31,6 @@ from repro.rl.env import Env, batched_env, rollout
 from repro.rl.networks import Network
 
 
-def shard_map_compat(fn, mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (top-level API vs experimental).
-
-    Shared by this module and ``rl.actor_learner`` (which generalizes the
-    data-parallel pattern here to the replay-driven actor–learner topology).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-_shard_map = shard_map_compat
-
-
 def make_distributed_a2c(env: Env, net: Network, cfg: a2c.A2CConfig,
                          mesh: Mesh, axis: str = "data"):
     """Returns (iteration, act_fn, benv_global) — iteration signature matches
@@ -148,10 +131,10 @@ def make_distributed_a2c(env: Env, net: Network, cfg: a2c.A2CConfig,
         return new_state, env_state, last_obs, {"loss": loss,
                                                 "reward": reward}
 
-    sharded = _shard_map(
-        shard_fn, mesh,
+    sharded = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(axis), P(axis), P()))
+        out_specs=(P(), P(axis), P(axis), P()), check_vma=False)
 
     @jax.jit
     def iteration(state, env_state, obs, key):

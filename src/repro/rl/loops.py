@@ -70,6 +70,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import metrics as metrics_lib
 from repro.core.qconfig import QuantConfig, QuantMode
@@ -127,6 +128,10 @@ class TrainResult:
     # topology="async" only: per sync, how many learner updates the retired
     # actor snapshot served for (the realized staleness, >= sync_every)
     actor_lags: List[int] = dataclasses.field(default_factory=list)
+    # the learner's mean loss over the last chunk, per record point
+    losses: List[float] = dataclasses.field(default_factory=list)
+    # the final batched env state (sharded over the actor axis under a mesh)
+    env_state: Any = None
 
 
 def make_scan_iteration(iteration: Callable, steps_per_call: int):
@@ -355,7 +360,7 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
     det_act = _det_act(act_fn)
     chunks: Dict[int, Callable] = {}   # compiled fused drivers by length
 
-    rewards, variances, divergences = [], [], []
+    rewards, variances, divergences, losses = [], [], [], []
     ckptr = _loop_checkpointer(checkpoint_dir, checkpoint_every, resume,
                                checkpoint_keep)
     i = 0
@@ -374,6 +379,15 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
             rewards = [float(r) for r in extra["rewards"]]
             variances = [float(v) for v in extra["action_variances"]]
             divergences = [list(d) for d in extra["divergences"]]
+            losses = [float(x) for x in extra.get("losses", [])]
+    if mesh is not None:
+        # commit the carry to the mesh once, under the programs' own
+        # specs: a donated input aliases its output only when both share
+        # a sharding, and arrays made off the mesh sit on one device
+        state = actor_learner.place(state, mesh,
+                                    actor_learner.mesh_specs(state))
+        env_state, obs = actor_learner.place((env_state, obs), mesh,
+                                             P("actor"))
     last_saved = i
     t0 = time.time()
     try:
@@ -402,15 +416,18 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                     if isinstance(state, actor_learner.ActorLearnerState) \
                     else state
                 k_run, k_eval = jax.random.split(k_run)
+                pview, obs_e, k_eval = _eval_inputs(
+                    mesh, (lview.params, lview.observers, lview.step), obs,
+                    k_eval)
                 if int8_act is not None:
                     # evaluate the actor configuration that actually
                     # collects data / gets deployed: with calib_batch the
                     # eval cache is calibrated (from the live obs) and
                     # runs the fused kernel
                     cb = getattr(cfg, "calib_batch", 0)
-                    obs_g = obs.reshape((-1,) + tuple(env.spec.obs_shape))
+                    obs_g = obs_e.reshape((-1,) + tuple(env.spec.obs_shape))
 
-                    def mint_eval(p=lview.params, og=obs_g, cb=cb):
+                    def mint_eval(p=pview[0], og=obs_g, cb=cb):
                         return actorq.make_actor_cache(
                             p, actor_backend,
                             calib_obs=actorq.calib_slice(og, cb)
@@ -426,11 +443,10 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                                        max_steps=env.spec.max_steps))
                 else:
                     r = float(evaluate(
-                        env, det_act,
-                        (lview.params, lview.observers, lview.step),
-                        k_eval, eval_episodes,
+                        env, det_act, pview, k_eval, eval_episodes,
                         max_steps=env.spec.max_steps))
                 rewards.append(r)
+                losses.append(float(last["loss"]))
                 variances.append(float(last.get(
                     "action_dist_variance", last.get("mean_q_var", 0.0))))
                 # staleness contract: the first true push happens at
@@ -454,7 +470,7 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                         "key": k_run},
                     extra={"iteration": i, "rewards": rewards,
                            "action_variances": variances,
-                           "divergences": divergences})
+                           "divergences": divergences, "losses": losses})
                 last_saved = i
                 if resilience is not None:
                     resilience.checkpoint_committed(ckptr, i)
@@ -471,7 +487,23 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
         state = state.learner
     return TrainResult(state=state, act_fn=act_fn, env=env, rewards=rewards,
                        action_variances=variances, wall_time_s=wall,
-                       algo_cfg=cfg, net=net, divergences=divergences)
+                       algo_cfg=cfg, net=net, divergences=divergences,
+                       losses=losses, env_state=env_state)
+
+
+def _eval_inputs(mesh, *trees):
+    """The inputs of an evaluation (params, obs, key), on one device.
+
+    Evaluation is one small unsharded program; on a mesh its inputs move
+    to the mesh's first device, because the compiler cannot partition the
+    quantized actor's Pallas kernels over the mesh.  They go through the
+    host: a ``device_put`` from an explicitly sharded mesh keeps the
+    mesh axes in the arrays' types.
+    """
+    if mesh is None:
+        return trees
+    return jax.device_put(jax.tree_util.tree_map(np.asarray, trees),
+                          mesh.devices.flat[0])
 
 
 def _guard_round(resilience, state, step, cfg, actor_backend,
@@ -554,7 +586,7 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
         if actorq.is_quantized(actor_backend) else None
     det_act = _det_act(progs.act_fn)
 
-    rewards, variances, actor_lags = [], [], []
+    rewards, variances, actor_lags, losses = [], [], [], []
     div_futs: List[Any] = []      # per-sync futures, materialized at the end
     updates_since_push = 0
     total_updates = 0             # learner updates dispatched (host-side)
@@ -580,11 +612,20 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
             rewards = [float(r) for r in extra["rewards"]]
             variances = [float(v) for v in extra["action_variances"]]
             actor_lags = [int(x) for x in extra["actor_lags"]]
+            losses = [float(x) for x in extra.get("losses", [])]
             div_futs = [np.asarray(d, dtype=np.float32)
                         for d in extra["divergences"]]
             updates_since_push = int(extra["updates_since_push"])
             total_updates = int(extra["total_updates"])
             snap_minted_at = int(extra["snap_minted_at"])
+    if mesh is not None:
+        # one placement, as in the synchronous driver (the snapshot too:
+        # a restored one comes off the mesh; later ones are minted on it)
+        learner = actor_learner.place(learner, mesh,
+                                      actor_learner.mesh_specs(learner))
+        wbuf, env_state, obs = actor_learner.place(
+            (wbuf, env_state, obs), mesh, P("actor"))
+        snap = actor_learner.place(snap, mesh, P())
     last_saved = i
     t0 = time.time()
     try:
@@ -610,7 +651,7 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
             if barrier:
                 learner = learner._replace(
                     extras=learner.extras._replace(replay=wbuf))
-            learner, _ = progs.learner_chunk(
+            learner, l_m = progs.learner_chunk(
                 learner, k_up, n_updates=c * cfg.updates_per_iter)
             total_updates += c * cfg.updates_per_iter
             updates_since_push += c * cfg.updates_per_iter
@@ -643,13 +684,16 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
                 updates_since_push = 0
             if i % record_every == 0 or i == iterations:
                 k_run, k_eval = jax.random.split(k_run)
+                pview, obs_e, k_eval = _eval_inputs(
+                    mesh, (learner.params, learner.observers, learner.step),
+                    obs, k_eval)
                 if int8_act is not None:
                     # same contract as the sync driver: eval the
                     # calibrated (fused) cache whenever the rollout
                     # actors run one
                     cb = getattr(cfg, "calib_batch", 0)
 
-                    def mint_eval(p=learner.params, og=obs, cb=cb):
+                    def mint_eval(p=pview[0], og=obs_e, cb=cb):
                         return actorq.make_actor_cache(
                             p, actor_backend,
                             calib_obs=actorq.calib_slice(og, cb)
@@ -665,11 +709,10 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
                                        max_steps=env.spec.max_steps))
                 else:
                     r = float(evaluate(
-                        env, det_act,
-                        (learner.params, learner.observers, learner.step),
-                        k_eval, eval_episodes,
+                        env, det_act, pview, k_eval, eval_episodes,
                         max_steps=env.spec.max_steps))
                 rewards.append(r)
+                losses.append(float(l_m["loss"]))
                 # neither async program surfaces an action-variance
                 # metric (same zeros the synchronous actor-learner
                 # topology records)
@@ -692,7 +735,7 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
                     extra={"iteration": i, "rewards": rewards,
                            "action_variances": variances,
                            "divergences": [d.tolist() for d in div_futs],
-                           "actor_lags": actor_lags,
+                           "actor_lags": actor_lags, "losses": losses,
                            "updates_since_push": updates_since_push,
                            "total_updates": total_updates,
                            "snap_minted_at": snap_minted_at})
@@ -711,7 +754,8 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
     return TrainResult(state=learner, act_fn=progs.act_fn, env=env,
                        rewards=rewards, action_variances=variances,
                        wall_time_s=wall, algo_cfg=cfg, net=net,
-                       divergences=divergences, actor_lags=actor_lags)
+                       divergences=divergences, actor_lags=actor_lags,
+                       losses=losses, env_state=env_state)
 
 
 @functools.lru_cache(maxsize=32)

@@ -195,22 +195,50 @@ def test_actor_learner_rejects_on_policy_algos():
                     algo_overrides=dict(SMALL_DQN))
 
 
+@pytest.mark.parametrize("topology", ["actor-learner", "async"])
+def test_train_on_one_device_mesh_places_carry(topology):
+    """``loops.train(mesh=...)`` places the carry on the mesh once (so the
+    donated chunks alias) and evaluates off it; the returned replay and
+    env state stay on the mesh, and the int8 actors' losses are finite."""
+    mesh = jax.make_mesh((1,), ("actor",))
+    res = loops.train("dqn", "cartpole", topology=topology, num_actors=2,
+                      sync_every=2, actor_backend="int8", calib_batch=8,
+                      steps_per_call=2, iterations=4, record_every=2,
+                      eval_episodes=2, mesh=mesh,
+                      algo_overrides=dict(SMALL_DQN))
+    assert len(res.losses) == 2 and np.isfinite(res.losses).all()
+    for leaf in jax.tree_util.tree_leaves((res.state.extras.replay,
+                                           res.env_state)):
+        assert isinstance(leaf.sharding, jax.sharding.NamedSharding)
+        assert leaf.sharding.spec[0] == "actor"
+
+
+def test_mesh_specs_shard_only_actor_axis_leaves():
+    env = make_env("cartpole")
+    cfg = dqn.DQNConfig(**SMALL_DQN)
+    net = make_network(env.spec.obs_shape, env.spec.n_actions)
+    al = actor_learner.ActorLearnerConfig(num_actors=2)
+    state = actor_learner.init(jax.random.PRNGKey(0), env, net, "dqn",
+                               cfg, al)
+    specs = actor_learner.mesh_specs(state)
+    P = jax.sharding.PartitionSpec
+    assert all(s == P("actor") for s in jax.tree_util.tree_leaves(
+        (specs.learner.extras.replay, specs.divergence),
+        is_leaf=lambda s: isinstance(s, P)))
+    assert all(s == P() for s in jax.tree_util.tree_leaves(
+        (specs.learner.params, specs.actor_params, specs.t),
+        is_leaf=lambda s: isinstance(s, P)))
+
+
 @pytest.mark.slow
 def test_actor_learner_eight_device_mesh():
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import contextlib
         import jax, jax.numpy as jnp, numpy as np
         from repro.rl import actor_learner, dqn
         from repro.rl.envs import make as make_env
         from repro.rl.networks import make_network
-
-        def mesh_ctx(mesh):
-            for name in ("set_mesh", "use_mesh"):
-                if hasattr(jax.sharding, name):
-                    return getattr(jax.sharding, name)(mesh)
-            return contextlib.nullcontext()
 
         env = make_env("cartpole")
         cfg = dqn.DQNConfig(n_envs=4, rollout_steps=4, updates_per_iter=2,
@@ -225,7 +253,7 @@ def test_actor_learner_eight_device_mesh():
             "dqn", env, net, cfg, al, mesh=mesh)
         env_state, obs = benv.reset(jax.random.PRNGKey(1))
         key = jax.random.PRNGKey(2)
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             for i in range(4):
                 key, k = jax.random.split(key)
                 state, env_state, obs, m = iteration(state, env_state, obs,

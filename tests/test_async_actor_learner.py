@@ -234,17 +234,11 @@ def test_async_actor_learner_four_device_mesh():
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        import contextlib
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
         from repro.rl import actor_learner, dqn
         from repro.rl.envs import make as make_env
         from repro.rl.networks import make_network
-
-        def mesh_ctx(mesh):
-            for name in ("set_mesh", "use_mesh"):
-                if hasattr(jax.sharding, name):
-                    return getattr(jax.sharding, name)(mesh)
-            return contextlib.nullcontext()
 
         env = make_env("cartpole")
         cfg = dqn.DQNConfig(n_envs=4, rollout_steps=4, updates_per_iter=2,
@@ -257,11 +251,16 @@ def test_async_actor_learner_four_device_mesh():
             "dqn", env, net, cfg, al, mesh=mesh)
         learner, wbuf = actor_learner.init_async(
             jax.random.PRNGKey(0), env, net, "dqn", cfg, al)
-        snap = progs.make_snapshot(learner)
         env_state, obs = progs.benv_global.reset(jax.random.PRNGKey(1))
+        # the carry goes onto the mesh once, under the programs' specs
+        learner = actor_learner.place(learner, mesh,
+                                      actor_learner.mesh_specs(learner))
+        wbuf, env_state, obs = actor_learner.place(
+            (wbuf, env_state, obs), mesh, P("actor"))
+        snap = progs.make_snapshot(learner)
         key = jax.random.PRNGKey(2)
         chunk, upd = 2, 4
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             for r in range(4):
                 key, k_it = jax.random.split(key)
                 k_roll, k_up = jax.random.split(k_it)
@@ -277,6 +276,9 @@ def test_async_actor_learner_four_device_mesh():
             assert jnp.isfinite(a_m["reward"]), a_m
         assert div.shape == (4,)
         assert np.isfinite(np.asarray(div)).all()
+        # the actor-axis carry stays spread over the mesh
+        for leaf in jax.tree_util.tree_leaves((wbuf, env_state, obs)):
+            assert len(leaf.sharding.device_set) == 4
         print("ASYNC_MESH_OK", float(l_m["loss"]))
     """)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))

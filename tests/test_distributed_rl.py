@@ -11,24 +11,6 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-MESH_CTX = textwrap.dedent("""
-    import contextlib
-    def mesh_ctx(mesh):
-        # newer jax requires an ambient mesh; older versions have no
-        # context manager and shard_map carries the mesh explicitly
-        for name in ("set_mesh", "use_mesh"):
-            if hasattr(jax.sharding, name):
-                return getattr(jax.sharding, name)(mesh)
-        return contextlib.nullcontext()
-""")
-
-# single source for the shim: the in-process tests exec the same code the
-# subprocess script embeds
-_ns = {"jax": jax}
-exec(MESH_CTX, _ns)
-_mesh_ctx = _ns["mesh_ctx"]
-
-
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -36,7 +18,7 @@ SCRIPT = textwrap.dedent("""
     from repro.rl import a2c, distributed
     from repro.rl.envs import make as make_env
     from repro.rl.networks import make_network
-""") + MESH_CTX + textwrap.dedent("""
+
     env = make_env("cartpole")
     cfg = a2c.A2CConfig(n_envs=16, n_steps=8, actor_backend=BACKEND)
     net = make_network(env.spec.obs_shape, env.spec.n_actions + 1)
@@ -46,7 +28,7 @@ SCRIPT = textwrap.dedent("""
         env, net, cfg, mesh)
     env_state, obs = benv.reset(jax.random.PRNGKey(1))
     key = jax.random.PRNGKey(2)
-    with mesh_ctx(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(5):
             key, k = jax.random.split(key)
             state, env_state, obs, m = iteration(state, env_state, obs, k)
@@ -69,7 +51,7 @@ def test_distributed_a2c_one_device():
     iteration, act_fn, benv = distributed.make_distributed_a2c(
         env, net, cfg, mesh)
     env_state, obs = benv.reset(jax.random.PRNGKey(1))
-    with _mesh_ctx(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(3):
             state, env_state, obs, m = iteration(
                 state, env_state, obs, jax.random.PRNGKey(10 + i))
@@ -92,7 +74,7 @@ def test_distributed_a2c_int8_actor_one_device():
     iteration, act_fn, benv = distributed.make_distributed_a2c(
         env, net, cfg, mesh)
     env_state, obs = benv.reset(jax.random.PRNGKey(1))
-    with _mesh_ctx(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(3):
             state, env_state, obs, m = iteration(
                 state, env_state, obs, jax.random.PRNGKey(10 + i))

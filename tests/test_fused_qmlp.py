@@ -39,6 +39,19 @@ def test_pack_unpack_int4_roundtrip(k):
                                   np.asarray(codes))
 
 
+def test_pack_int4_holds_k_halves_in_nibbles():
+    """Byte row i = code row i (low nibble) | code row ceil(K/2)+i (high
+    nibble): each nibble plane is a contiguous K half, and an odd K pads
+    the last high nibble with a zero code."""
+    k = 5
+    codes = jax.random.randint(jax.random.PRNGKey(0), (k, 3), -8, 8
+                               ).astype(jnp.int8)
+    lo, hi = affine.int4_halves(affine.pack_int4(codes))
+    np.testing.assert_array_equal(np.asarray(lo), np.asarray(codes[:3]))
+    np.testing.assert_array_equal(np.asarray(hi[:2]), np.asarray(codes[3:]))
+    np.testing.assert_array_equal(np.asarray(hi[2]), 0)
+
+
 def test_quantize_with_params_matches_dynamic():
     """Static requant with params derived from the same tensor is the
     dynamic quantizer bit for bit — the fused kernel's core contract."""

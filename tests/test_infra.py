@@ -300,3 +300,27 @@ def test_dryrun_subprocess_end_to_end():
         capture_output=True, text=True, env=env, timeout=500)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "All dry-runs compiled successfully" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# Persistent compilation cache placement (entry points)
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_leaves_env_dir_to_jax(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    monkeypatch.setenv(compile_cache.ENV_DIR, str(tmp_path))
+    was = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
