@@ -1,0 +1,101 @@
+"""Every file the benchmark finds by name loads, and a run without a TPU
+gives no result."""
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+import run
+from drive_actor_learner import _program_widths
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    SPEC = json.load(_f)
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def test_top_level_keys():
+    """BENCHMARK.json has exactly the contract's keys."""
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert SPEC["paths"] == ["bench"]
+    assert os.path.exists(os.path.join(ROOT, SPEC["command"][1]))
+
+
+def test_names():
+    """Names are unique and made of the allowed characters."""
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in SPEC[group]]
+        assert len(set(names)) == len(names)
+        assert all(NAME.match(n) for n in names), names
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_load(cell):
+    """A cell's configuration, traffic, limits and readers load."""
+    spec = run.load_cell(cell)
+    assert spec["config"]["name"] == spec["cell"]["config"]
+    assert _program_widths(spec["config"]["policy"])
+    assert spec["limits"], "a cell compares at least one number"
+    assert any(m["name"] == "setup_s" for m in spec["end_to_end"])
+    for m in spec["per_layer"]:
+        assert callable(run.load_reader(m["name"]))
+
+
+def test_config_entries_point_at_their_files():
+    """Each configuration entry names its own file."""
+    for c in SPEC["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            body = json.load(f)
+        assert body["name"] == c["name"]
+        assert body["reduced"] == c["reduced"]
+
+
+def test_per_layer_metrics_list_cells_that_report_what_they_move():
+    """Per-layer metrics list only cells that report what they move."""
+    e2e = {m["name"]: m for m in SPEC["end_to_end"]}
+    for m in SPEC["per_layer"]:
+        moved = e2e[m["moves"]]
+        for cell in m.get("workloads", CELLS):
+            assert cell in CELLS
+            assert cell in moved.get("workloads", CELLS)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_no_result_without_a_tpu(cell):
+    """On the CPU a run exits non-zero and prints nothing."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "3000000001", "--seconds", "2", "--trace", "0"],
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=300)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" in p.stderr
+
+
+def test_unknown_device_kind_is_an_error(monkeypatch):
+    """A chip missing from peaks.json is an error."""
+    import jax
+
+    class Fake:
+        platform, device_kind = "tpu", "TPU v99"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Fake()])
+    with pytest.raises(run.BenchError, match="no peaks"):
+        run.find_devices(1)
+
+
+def test_peaks_cover_the_chip_the_benchmark_runs_on():
+    """The v5e peaks are the published ones."""
+    with open(os.path.join(BENCH, "peaks.json")) as f:
+        peaks = json.load(f)
+    v5e = peaks["devices"]["TPU v5 lite"]
+    assert v5e["int8_ops"] == 393e12 and v5e["bf16_flops"] == 197e12
+    assert v5e["hbm_bytes_per_s"] == 819e9
