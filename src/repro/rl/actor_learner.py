@@ -245,6 +245,7 @@ def _make_learner_phase(parts: _AlgoParts, cfg, use_per: bool,
     learn = parts.learn
 
     def learner_phase(learner, k_updates, total_size, n_updates, reduce):
+        @common.phase("learner_update")
         def one_update(st, k):
             keys_a = k[None] if local_actors == 1 \
                 else jax.random.split(k, local_actors)
@@ -254,22 +255,25 @@ def _make_learner_phase(parts: _AlgoParts, cfg, use_per: bool,
                 # priority pushes stay per-shard, inside the shard_map —
                 # the actor axis never gathers
                 beta = common.per_beta(st, cfg)
-                shards, idx, w = rb.per_sample_sharded(
-                    st.extras.replay, keys_a, per_actor_batch, beta)
-                batch = jax.tree_util.tree_map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), shards)
+                with common.phase("replay_sample"):
+                    shards, idx, w = rb.per_sample_sharded(
+                        st.extras.replay, keys_a, per_actor_batch, beta)
+                    batch = jax.tree_util.tree_map(
+                        lambda x: x.reshape((-1,) + x.shape[2:]), shards)
                 st, (loss, td_abs) = learn(st, batch, total_size,
                                            weights=w.reshape(-1),
                                            reduce=reduce)
-                per = rb.per_update_priorities_sharded(
-                    st.extras.replay, idx, td_abs.reshape(idx.shape),
-                    cfg.priority_exponent)
+                with common.phase("replay_sample"):
+                    per = rb.per_update_priorities_sharded(
+                        st.extras.replay, idx, td_abs.reshape(idx.shape),
+                        cfg.priority_exponent)
                 st = st._replace(extras=st.extras._replace(replay=per))
                 return st, loss
-            shards = rb.replay_sample_sharded(st.extras.replay, keys_a,
-                                              per_actor_batch)
-            batch = jax.tree_util.tree_map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), shards)
+            with common.phase("replay_sample"):
+                shards = rb.replay_sample_sharded(st.extras.replay, keys_a,
+                                                  per_actor_batch)
+                batch = jax.tree_util.tree_map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), shards)
             st, (loss, _) = learn(st, batch, total_size, reduce=reduce)
             return st, loss
 
@@ -532,16 +536,17 @@ def make_actor_learner(algo: str, env: Env, net, cfg,
             benv_local, policy, actor_params, env_state, obs, k_roll,
             cfg.rollout_steps)
 
-        flat = jax.tree_util.tree_map(to_shards, traj)
-        replay = add_sharded(
-            learner.extras.replay,
-            rb.Transition(flat.obs, flat.action, flat.reward, flat.done,
-                          flat.next_obs))
-        learner = learner._replace(
-            extras=learner.extras._replace(replay=replay))
-        total_size = rb.replay_total_size(replay)
-        if axis_name is not None:
-            total_size = jax.lax.psum(total_size, axis_name)
+        with common.phase("replay_insert"):
+            flat = jax.tree_util.tree_map(to_shards, traj)
+            replay = add_sharded(
+                learner.extras.replay,
+                rb.Transition(flat.obs, flat.action, flat.reward, flat.done,
+                              flat.next_obs))
+            learner = learner._replace(
+                extras=learner.extras._replace(replay=replay))
+            total_size = rb.replay_total_size(replay)
+            if axis_name is not None:
+                total_size = jax.lax.psum(total_size, axis_name)
 
         # --- learner phase: per-shard sampling, fp32 updates -------------
         learner, losses = learner_phase(learner, k_updates, total_size,
@@ -551,49 +556,51 @@ def make_actor_learner(algo: str, env: Env, net, cfg,
         # first push at t == sync_every (t=0 is init, where the actors hold
         # a fresh copy by construction — not a sync, and not a divergence
         # sample); between pushes actors run the stale params + stale cache
-        t = state.t + 1
-        do_sync = (t % al.sync_every) == 0
-        actor_params = jax.tree_util.tree_map(
-            lambda a, p: jnp.where(do_sync, p, a), actor_params,
-            learner.params)
-        if int8:
-            # repack the int cache only at true pushes — between syncs the
-            # actor params are unchanged and the cache is bitwise-stable.
-            # calib_batch: the repack also refreshes the static activation
-            # scales from the actors' current observations, so the fused
-            # kernel's requant ranges track the data distribution at the
-            # same cadence as the params.
-            def repack(p):
-                calib_obs = None
-                if cfg.calib_batch:
-                    # the cache is carried replicated over the actor axis
-                    # (P() in mesh_specs): on a mesh, gather the
-                    # calibration batch so every device derives identical
-                    # scales (collective only inside the sync branch)
-                    calib_obs = obs if axis_name is None else \
-                        jax.lax.all_gather(obs, axis_name, axis=0,
-                                           tiled=True)
-                    calib_obs = actorq.calib_slice(calib_obs,
-                                                   cfg.calib_batch)
-                return actorq.make_actor_cache(
-                    p, cfg.actor_backend, calib_obs=calib_obs,
-                    backend=cfg.kernel_backend)
+        with common.phase("param_push"):
+            t = state.t + 1
+            do_sync = (t % al.sync_every) == 0
+            actor_params = jax.tree_util.tree_map(
+                lambda a, p: jnp.where(do_sync, p, a), actor_params,
+                learner.params)
+            if int8:
+                # repack the int cache only at true pushes — between syncs
+                # the actor params are unchanged and the cache is
+                # bitwise-stable.  calib_batch: the repack also refreshes
+                # the static activation scales from the actors' current
+                # observations, so the fused kernel's requant ranges track
+                # the data distribution at the same cadence as the params.
+                def repack(p):
+                    calib_obs = None
+                    if cfg.calib_batch:
+                        # the cache is carried replicated over the actor
+                        # axis (P() in mesh_specs): on a mesh, gather the
+                        # calibration batch so every device derives
+                        # identical scales (collective only inside the
+                        # sync branch)
+                        calib_obs = obs if axis_name is None else \
+                            jax.lax.all_gather(obs, axis_name, axis=0,
+                                               tiled=True)
+                        calib_obs = actorq.calib_slice(calib_obs,
+                                                       cfg.calib_batch)
+                    return actorq.make_actor_cache(
+                        p, cfg.actor_backend, calib_obs=calib_obs,
+                        backend=cfg.kernel_backend)
 
-            cache = jax.lax.cond(
+                cache = jax.lax.cond(
+                    do_sync,
+                    repack,
+                    lambda _: state.actor_cache,
+                    actor_params)
+            else:
+                cache = state.actor_cache
+            # divergence is recorded at sync points only (lax.cond keeps
+            # the extra head passes off the non-sync iterations); between
+            # syncs the last recorded value carries through
+            div = jax.lax.cond(
                 do_sync,
-                repack,
-                lambda _: state.actor_cache,
-                actor_params)
-        else:
-            cache = state.actor_cache
-        # divergence is recorded at sync points only (lax.cond keeps the
-        # extra head passes off the non-sync iterations); between syncs the
-        # last recorded value carries through
-        div = jax.lax.cond(
-            do_sync,
-            lambda args: divergence(*args),
-            lambda args: state.divergence,
-            (learner, actor_params, cache, obs))
+                lambda args: divergence(*args),
+                lambda args: state.divergence,
+                (learner, actor_params, cache, obs))
 
         reward = jnp.sum(traj.reward) / jnp.maximum(jnp.sum(traj.done),
                                                     1.0)
@@ -675,6 +682,7 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
     to_shards = _make_to_shards(local_actors, envs_per_actor)
     add_sharded = rb.per_add_sharded if use_per else rb.replay_add_sharded
 
+    @common.phase("param_push")
     def snapshot_core(learner: common.TrainState, obs,
                       axis_name) -> ActorSnapshot:
         """Param push: mint the actors' next (packed) snapshot.  ``obs``
@@ -716,10 +724,11 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
             env_state, obs, traj = rollout(
                 benv_local, policy, snap.params, env_state, obs, k,
                 cfg.rollout_steps)
-            flat = jax.tree_util.tree_map(to_shards, traj)
-            wbuf = add_sharded(
-                wbuf, rb.Transition(flat.obs, flat.action, flat.reward,
-                                    flat.done, flat.next_obs))
+            with common.phase("replay_insert"):
+                flat = jax.tree_util.tree_map(to_shards, traj)
+                wbuf = add_sharded(
+                    wbuf, rb.Transition(flat.obs, flat.action, flat.reward,
+                                        flat.done, flat.next_obs))
             r = jnp.sum(traj.reward) / jnp.maximum(jnp.sum(traj.done), 1.0)
             return (env_state, obs, wbuf), r
 
@@ -752,6 +761,7 @@ def make_async_actor_learner(algo: str, env: Env, net, cfg,
     _div = _make_divergence(parts, int8, local_actors, envs_per_actor,
                             obs_shape)
 
+    @common.phase("param_push")
     def divergence_core(learner, snap, obs):
         return _div(learner, snap.params, snap.cache, obs)
 

@@ -1,4 +1,5 @@
-"""Shared RL plumbing: train-state, QAT context wiring, eval helpers."""
+"""Shared RL plumbing: train-state, QAT context wiring, eval helpers, and
+the phase names an iteration's work is traced under."""
 from __future__ import annotations
 
 from typing import Any, Dict, NamedTuple
@@ -10,6 +11,23 @@ from repro.core import fake_quant, ptq
 from repro.core.qconfig import QuantConfig
 from repro.optim.adam import AdamState
 from repro.rl import buffer as rb
+
+
+# The phases of an RL iteration.  Each wraps its work in
+# ``jax.named_scope(<phase>)``, which names it in the HLO ``op_name`` of every
+# operation traced under it and in a device trace's ``tf_op`` stats; it
+# changes nothing else in the compiled program.  Phases nest
+# (``replay_sample`` inside ``learner_update``); the innermost one owns an
+# operation.
+PHASES = ("actor_forward", "env_step", "replay_insert", "replay_sample",
+          "learner_update", "param_push")
+
+
+def phase(name: str):
+    """``jax.named_scope`` of one of ``PHASES``."""
+    if name not in PHASES:
+        raise ValueError(f"phase must be one of {PHASES}, got {name!r}")
+    return jax.named_scope(name)
 
 
 class TrainState(NamedTuple):
@@ -101,12 +119,14 @@ def per_learner_step(state: TrainState, key, cfg, update_fn):
     ``*_sharded`` buffer ops — see ``rl.actor_learner``.)
     """
     beta = per_beta(state, cfg)
-    batch, idx, w = rb.per_sample(state.extras.replay, key,
-                                  cfg.batch_size, beta)
+    with phase("replay_sample"):
+        batch, idx, w = rb.per_sample(state.extras.replay, key,
+                                      cfg.batch_size, beta)
     state, (loss, td_abs) = update_fn(
         state, batch, state.extras.replay.replay.size, weights=w)
-    per = rb.per_update_priorities(state.extras.replay, idx, td_abs,
-                                   cfg.priority_exponent)
+    with phase("replay_sample"):
+        per = rb.per_update_priorities(state.extras.replay, idx, td_abs,
+                                       cfg.priority_exponent)
     return state._replace(
         extras=state.extras._replace(replay=per)), loss
 

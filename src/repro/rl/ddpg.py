@@ -242,31 +242,38 @@ def make_iteration(env: Env, nets: DDPGNets, cfg: DDPGConfig):
     @jax.jit
     def iteration(state: common.TrainState, env_state, obs, key):
         k_roll, k_up = jax.random.split(key)
-        policy_kw = {}
-        if actorq.is_quantized(cfg.actor_backend) and cfg.calib_batch:
-            # static-requant mode (see dqn.make_iteration)
-            policy_kw["qparams"] = actorq.make_actor_cache(
-                state.params, cfg.actor_backend,
-                calib_obs=actorq.calib_slice(obs, cfg.calib_batch),
-                backend=cfg.kernel_backend)
-        policy = build_policy(state.params, state.observers, state.step,
-                              **policy_kw)
+        # the actors' int8 cache, packed from the live params: the fused
+        # loop's param push
+        with common.phase("param_push"):
+            policy_kw = {}
+            if actorq.is_quantized(cfg.actor_backend) and cfg.calib_batch:
+                # static-requant mode (see dqn.make_iteration)
+                policy_kw["qparams"] = actorq.make_actor_cache(
+                    state.params, cfg.actor_backend,
+                    calib_obs=actorq.calib_slice(obs, cfg.calib_batch),
+                    backend=cfg.kernel_backend)
+            policy = build_policy(state.params, state.observers, state.step,
+                                  **policy_kw)
         env_state, obs, traj = rollout(benv, policy, state.params,
                                        env_state, obs, k_roll,
                                        cfg.rollout_steps)
-        flat = jax.tree_util.tree_map(
-            lambda x: x.reshape((-1,) + x.shape[2:]), traj)
-        add = rb.per_add if use_per else rb.replay_add_batch
-        replay = add(
-            state.extras.replay,
-            rb.Transition(flat.obs, flat.action, flat.reward, flat.done,
-                          flat.next_obs))
+        with common.phase("replay_insert"):
+            flat = jax.tree_util.tree_map(
+                lambda x: x.reshape((-1,) + x.shape[2:]), traj)
+            add = rb.per_add if use_per else rb.replay_add_batch
+            replay = add(
+                state.extras.replay,
+                rb.Transition(flat.obs, flat.action, flat.reward, flat.done,
+                              flat.next_obs))
         state = state._replace(extras=state.extras._replace(replay=replay))
 
+        @common.phase("learner_update")
         def one_update(st, k):
             if use_per:
                 return common.per_learner_step(st, k, cfg, update)
-            batch = rb.replay_sample(st.extras.replay, k, cfg.batch_size)
+            with common.phase("replay_sample"):
+                batch = rb.replay_sample(st.extras.replay, k,
+                                         cfg.batch_size)
             st, (loss, _) = update(st, batch, st.extras.replay.size)
             return st, loss
         state, losses = jax.lax.scan(

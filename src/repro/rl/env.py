@@ -20,6 +20,8 @@ from typing import Any, Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.rl import common
+
 State = Any
 Obs = jnp.ndarray
 
@@ -130,6 +132,8 @@ def rollout(env: Env, policy_fn, params, state, obs, key, n_steps: int,
     policy_fn(params, obs, key) -> (action, aux) — aux is carried into the
     trajectory (logits for exploration analysis, values for A2C/PPO...).
     Returns (final_state, final_obs, StepOut trajectory [n_steps, ...]).
+    The policy runs under the ``actor_forward`` phase and the env step
+    (with its auto-reset) under ``env_step`` (``common.PHASES``).
 
     A ``StatefulPolicy`` ``policy_fn`` requires ``env`` to be wrapped
     with :func:`attach_policy_state`: the policy reads and writes the
@@ -141,13 +145,15 @@ def rollout(env: Env, policy_fn, params, state, obs, key, n_steps: int,
     def one(carry, key):
         state, obs = carry
         k_act, k_env = jax.random.split(key)
-        if stateful:
-            inner, ps = state
-            action, ps, aux = policy_fn.apply(params, obs, ps, k_act)
-            state = (inner, ps)
-        else:
-            action, aux = policy_fn(params, obs, k_act)
-        state, next_obs, reward, done = stepper(state, action, k_env)
+        with common.phase("actor_forward"):
+            if stateful:
+                inner, ps = state
+                action, ps, aux = policy_fn.apply(params, obs, ps, k_act)
+                state = (inner, ps)
+            else:
+                action, aux = policy_fn(params, obs, k_act)
+        with common.phase("env_step"):
+            state, next_obs, reward, done = stepper(state, action, k_env)
         out = StepOut(obs=obs, action=action, reward=reward, done=done,
                       next_obs=next_obs, logits_or_value=aux)
         return (state, next_obs), out

@@ -358,6 +358,9 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
     # stable act-fn identity across the run -> evaluate() compiles once;
     # observers/step ride along in the params slot as traced inputs
     det_act = _det_act(act_fn)
+    evaluate_at = _make_record_eval(env, cfg, mesh, actor_backend,
+                                    kernel_backend, int8_act, det_act,
+                                    eval_episodes, resilience)
     chunks: Dict[int, Callable] = {}   # compiled fused drivers by length
 
     rewards, variances, divergences, losses = [], [], [], []
@@ -403,8 +406,9 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
             n = min(max(steps_per_call, 1), next_stop - i)
             if n not in chunks:
                 chunks[n] = make_scan_iteration(iteration, n)
-            state, env_state, obs, k_run, metrics = chunks[n](
-                state, env_state, obs, k_run)
+            with jax.profiler.TraceAnnotation("train.chunk"):
+                state, env_state, obs, k_run, metrics = chunks[n](
+                    state, env_state, obs, k_run)
             i += n
             if resilience is not None:
                 state = _guard_round(resilience, state, i, cfg,
@@ -415,36 +419,10 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                 lview = state.learner \
                     if isinstance(state, actor_learner.ActorLearnerState) \
                     else state
-                k_run, k_eval = jax.random.split(k_run)
-                pview, obs_e, k_eval = _eval_inputs(
-                    mesh, (lview.params, lview.observers, lview.step), obs,
-                    k_eval)
-                if int8_act is not None:
-                    # evaluate the actor configuration that actually
-                    # collects data / gets deployed: with calib_batch the
-                    # eval cache is calibrated (from the live obs) and
-                    # runs the fused kernel
-                    cb = getattr(cfg, "calib_batch", 0)
-                    obs_g = obs_e.reshape((-1,) + tuple(env.spec.obs_shape))
-
-                    def mint_eval(p=pview[0], og=obs_g, cb=cb):
-                        return actorq.make_actor_cache(
-                            p, actor_backend,
-                            calib_obs=actorq.calib_slice(og, cb)
-                            if cb else None,
-                            backend=kernel_backend)
-
-                    qparams = mint_eval()
-                    if resilience is not None:
-                        qparams = resilience.on_eval_cache(qparams, i,
-                                                           mint_eval)
-                    r = float(evaluate(env, int8_act, qparams, k_eval,
-                                       eval_episodes,
-                                       max_steps=env.spec.max_steps))
-                else:
-                    r = float(evaluate(
-                        env, det_act, pview, k_eval, eval_episodes,
-                        max_steps=env.spec.max_steps))
+                with jax.profiler.TraceAnnotation("train.eval"):
+                    r, k_run = evaluate_at(
+                        (lview.params, lview.observers, lview.step), obs,
+                        k_run, i)
                 rewards.append(r)
                 losses.append(float(last["loss"]))
                 variances.append(float(last.get(
@@ -465,12 +443,14 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                 # run continues the PRNG chain bitwise.  Cadence never
                 # clips chunks — the chunk-boundary sequence is a function
                 # of i alone, identical with or without checkpointing.
-                ckptr.save_async(
-                    i, {"state": state, "env_state": env_state, "obs": obs,
-                        "key": k_run},
-                    extra={"iteration": i, "rewards": rewards,
-                           "action_variances": variances,
-                           "divergences": divergences, "losses": losses})
+                with jax.profiler.TraceAnnotation("train.checkpoint"):
+                    ckptr.save_async(
+                        i, {"state": state, "env_state": env_state,
+                            "obs": obs, "key": k_run},
+                        extra={"iteration": i, "rewards": rewards,
+                               "action_variances": variances,
+                               "divergences": divergences,
+                               "losses": losses})
                 last_saved = i
                 if resilience is not None:
                     resilience.checkpoint_committed(ckptr, i)
@@ -504,6 +484,41 @@ def _eval_inputs(mesh, *trees):
         return trees
     return jax.device_put(jax.tree_util.tree_map(np.asarray, trees),
                           mesh.devices.flat[0])
+
+
+def _make_record_eval(env, cfg, mesh, actor_backend, kernel_backend,
+                      int8_act, det_act, eval_episodes, resilience):
+    """``evaluate_at(params_view, obs, k_run, i) -> (reward, k_run)``: the
+    record-point evaluation both drivers run, on the learner's
+    ``(params, observers, step)``.
+
+    Quantized actors are evaluated in the configuration that collects data
+    and gets deployed: with ``calib_batch`` the eval cache is calibrated
+    from the live observations and runs the fused kernel."""
+    cb = getattr(cfg, "calib_batch", 0)
+
+    def evaluate_at(params_view, obs, k_run, i):
+        k_run, k_eval = jax.random.split(k_run)
+        pview, obs_e, k_eval = _eval_inputs(mesh, params_view, obs, k_eval)
+        if int8_act is None:
+            r = evaluate(env, det_act, pview, k_eval, eval_episodes,
+                         max_steps=env.spec.max_steps)
+            return float(r), k_run
+        obs_g = obs_e.reshape((-1,) + tuple(env.spec.obs_shape))
+
+        def mint_eval(p=pview[0], og=obs_g):
+            return actorq.make_actor_cache(
+                p, actor_backend,
+                calib_obs=actorq.calib_slice(og, cb) if cb else None,
+                backend=kernel_backend)
+
+        qparams = mint_eval()
+        if resilience is not None:
+            qparams = resilience.on_eval_cache(qparams, i, mint_eval)
+        r = evaluate(env, int8_act, qparams, k_eval, eval_episodes,
+                     max_steps=env.spec.max_steps)
+        return float(r), k_run
+    return evaluate_at
 
 
 def _guard_round(resilience, state, step, cfg, actor_backend,
@@ -585,6 +600,9 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
     int8_act = actorq.make_act_fn(env.spec, backend=kernel_backend) \
         if actorq.is_quantized(actor_backend) else None
     det_act = _det_act(progs.act_fn)
+    evaluate_at = _make_record_eval(env, cfg, mesh, actor_backend,
+                                    kernel_backend, int8_act, det_act,
+                                    eval_episodes, resilience)
 
     rewards, variances, actor_lags, losses = [], [], [], []
     div_futs: List[Any] = []      # per-sync futures, materialized at the end
@@ -646,13 +664,14 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
             k_roll, k_up = jax.random.split(k_it)
             if barrier:
                 wbuf = learner.extras.replay
-            env_state, obs, wbuf, _ = progs.actor_chunk(
-                snap, env_state, obs, wbuf, k_roll, n_chunks=c)
-            if barrier:
-                learner = learner._replace(
-                    extras=learner.extras._replace(replay=wbuf))
-            learner, l_m = progs.learner_chunk(
-                learner, k_up, n_updates=c * cfg.updates_per_iter)
+            with jax.profiler.TraceAnnotation("train.chunk"):
+                env_state, obs, wbuf, _ = progs.actor_chunk(
+                    snap, env_state, obs, wbuf, k_roll, n_chunks=c)
+                if barrier:
+                    learner = learner._replace(
+                        extras=learner.extras._replace(replay=wbuf))
+                learner, l_m = progs.learner_chunk(
+                    learner, k_up, n_updates=c * cfg.updates_per_iter)
             total_updates += c * cfg.updates_per_iter
             updates_since_push += c * cfg.updates_per_iter
             i += c
@@ -683,34 +702,10 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
                 div_futs.append(progs.divergence(learner, snap, obs))
                 updates_since_push = 0
             if i % record_every == 0 or i == iterations:
-                k_run, k_eval = jax.random.split(k_run)
-                pview, obs_e, k_eval = _eval_inputs(
-                    mesh, (learner.params, learner.observers, learner.step),
-                    obs, k_eval)
-                if int8_act is not None:
-                    # same contract as the sync driver: eval the
-                    # calibrated (fused) cache whenever the rollout
-                    # actors run one
-                    cb = getattr(cfg, "calib_batch", 0)
-
-                    def mint_eval(p=pview[0], og=obs_e, cb=cb):
-                        return actorq.make_actor_cache(
-                            p, actor_backend,
-                            calib_obs=actorq.calib_slice(og, cb)
-                            if cb else None,
-                            backend=kernel_backend)
-
-                    qparams = mint_eval()
-                    if resilience is not None:
-                        qparams = resilience.on_eval_cache(qparams, i,
-                                                           mint_eval)
-                    r = float(evaluate(env, int8_act, qparams, k_eval,
-                                       eval_episodes,
-                                       max_steps=env.spec.max_steps))
-                else:
-                    r = float(evaluate(
-                        env, det_act, pview, k_eval, eval_episodes,
-                        max_steps=env.spec.max_steps))
+                with jax.profiler.TraceAnnotation("train.eval"):
+                    r, k_run = evaluate_at(
+                        (learner.params, learner.observers, learner.step),
+                        obs, k_run, i)
                 rewards.append(r)
                 losses.append(float(l_m["loss"]))
                 # neither async program surfaces an action-variance
@@ -726,19 +721,21 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
                 # or without checkpointing.  Host-copying here blocks
                 # this thread on the in-flight chunks, but never inserts
                 # a device barrier into the dispatch chain itself.
-                div_futs = [np.asarray(d) for d in div_futs]
-                ckptr.save_async(
-                    i, {"learner": learner,
-                        "wbuf": None if barrier else wbuf,
-                        "env_state": env_state, "obs": obs, "snap": snap,
-                        "key": k_run},
-                    extra={"iteration": i, "rewards": rewards,
-                           "action_variances": variances,
-                           "divergences": [d.tolist() for d in div_futs],
-                           "actor_lags": actor_lags, "losses": losses,
-                           "updates_since_push": updates_since_push,
-                           "total_updates": total_updates,
-                           "snap_minted_at": snap_minted_at})
+                with jax.profiler.TraceAnnotation("train.checkpoint"):
+                    div_futs = [np.asarray(d) for d in div_futs]
+                    ckptr.save_async(
+                        i, {"learner": learner,
+                            "wbuf": None if barrier else wbuf,
+                            "env_state": env_state, "obs": obs, "snap": snap,
+                            "key": k_run},
+                        extra={"iteration": i, "rewards": rewards,
+                               "action_variances": variances,
+                               "divergences": [d.tolist()
+                                           for d in div_futs],
+                               "actor_lags": actor_lags, "losses": losses,
+                               "updates_since_push": updates_since_push,
+                               "total_updates": total_updates,
+                               "snap_minted_at": snap_minted_at})
                 last_saved = i
                 if resilience is not None:
                     resilience.checkpoint_committed(ckptr, i)
