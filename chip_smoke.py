@@ -8,7 +8,8 @@ and a few training iterations each:
   (a) device   the first device is a TPU, and ``kernel_backend="auto"``
                resolves to the Pallas kernels;
   (b) conv     DQN on catch, 4 int8 actors, actor-learner topology, the
-               ATARI_DQN conv net (im2col through ``int8_matmul``);
+               ATARI_DQN conv net (tap-major int8 patches through
+               ``int8_matmul``);
   (c) mlp      DQN on cartpole with DEPLOY_POLICY_II and calibrated
                (fused-kernel) int8 and int4 actors, in the actor-learner
                and async topologies;
@@ -156,7 +157,7 @@ def _mlp_kwargs():
 
 
 def phase_conv():
-    """(b): the conv actor, per-layer int8 GEMMs through im2col."""
+    """(b): the conv actor, per-layer int8 GEMMs on tap-major patches."""
     res = _train("catch", "actor-learner", "int8", _conv_kwargs())
     _finite(res, "conv actor-learner int8")
     return res

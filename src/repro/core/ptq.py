@@ -69,8 +69,11 @@ class PackedTensor(NamedTuple):
 
     Sub-8-bit weights (``bits <= 4``) store ``codes`` *packed*: two int4
     codes per int8 byte along the GEMM contraction axis, already in the
-    kernel's ``(K, N)`` layout (conv kernels are pre-transposed from HWIO
-    to the im2col ``(C_in*kh*kw, C_out)`` feature order).  ``orig_shape``
+    kernel's ``(K, N)`` layout (conv kernels are HWIO reshaped to the
+    tap-major ``(kh*kw*C_in, C_out)`` feature order of
+    ``rl.actorq.int8_conv2d``; a checkpoint does not record that order,
+    so a restored conv cache is re-minted,
+    ``rl.actorq.repack_restored_cache``).  ``orig_shape``
     carries the unpacked weight shape; ``None`` means codes are stored in
     the weight's natural layout (the int8 path).
     """
@@ -94,12 +97,10 @@ class PackedTensor(NamedTuple):
     def dequantize(self, dtype=jnp.float32) -> jnp.ndarray:
         p = affine.AffineParams(self.delta, self.zero_point, self.bits)
         codes = self.unpacked_codes()
-        if self.orig_shape is not None and len(self.orig_shape) == 4:
-            # packed conv codes live in im2col (C_in*kh*kw, C_out) layout;
-            # restore HWIO so delta/zero_point broadcast as at pack time
-            kh, kw, ci, co = self.orig_shape
-            codes = codes.reshape(ci, kh, kw, co).transpose(1, 2, 0, 3)
-        elif self.orig_shape is not None:
+        if self.orig_shape is not None:
+            # packed codes are the weight reshaped to (K, N) (a conv's HWIO
+            # to (kh*kw*C_in, C_out)); restore it so delta/zero_point
+            # broadcast as at pack time
             codes = codes.reshape(self.orig_shape)
         return affine.dequantize_from_int(codes, p, dtype)
 
@@ -137,15 +138,11 @@ def _pack_leaf(leaf: jnp.ndarray, bits: int,
     if bits > 4:
         return PackedTensor(codes, p.delta, p.zero_point, bits,
                             col_scale, col_zero)
-    # sub-8-bit: pre-transpose to the GEMM contraction layout and pack
-    # two codes per byte along K (see PackedTensor docstring)
-    if leaf.ndim == 4:
-        kh, kw, ci, co = codes.shape
-        codes = codes.transpose(2, 0, 1, 3).reshape(kh * kw * ci, co)
-    else:
-        codes = codes.reshape(-1, n)
-    return PackedTensor(affine.pack_int4(codes), p.delta, p.zero_point,
-                        bits, col_scale, col_zero,
+    # sub-8-bit: reshape to the GEMM contraction layout (a conv's HWIO to
+    # tap-major (kh*kw*C_in, C_out)) and pack two codes per byte along K
+    # (see PackedTensor docstring)
+    return PackedTensor(affine.pack_int4(codes.reshape(-1, n)), p.delta,
+                        p.zero_point, bits, col_scale, col_zero,
                         orig_shape=tuple(leaf.shape))
 
 

@@ -14,9 +14,11 @@ Quantization scheme (matches ``core.ptq`` exactly, so the int8 path and the
 fake-quant simulation share one quantizer):
 
 * dense weights   — per-tensor affine int8 codes (``core.affine``),
-* conv weights    — per-output-channel int8 codes, computed in int8 via an
-  im2col lowering: patches through the same W8A8 GEMM with the per-channel
-  scales in the kernel's per-column dequant epilogue,
+* conv weights    — per-output-channel int8 codes, computed in int8 via
+  tap-major int8 patches: the conv input is quantized once, its 3x3 taps
+  are row shifts of the int8 codes concatenated along K in ``(kh, kw,
+  C_in)`` order, and the patches go through the same W8A8 GEMM with the
+  per-channel scales in the kernel's per-column dequant epilogue,
 * activations     — dynamic per-tensor quantization at each dense/conv
   input (computed on the fly from the live batch range; no calibration).
 
@@ -44,6 +46,8 @@ Entry points:
   layer
 * ``make_actor_cache(params, backend, calib_obs=...)`` -> the one-stop
   pack(+calibrate) used at every cache-refresh site
+* ``repack_restored_cache(cache, params, backend)`` -> a checkpointed
+  cache in the packing order ``int8_conv2d`` reads
 * ``quantized_apply(qparams, obs)``          -> head outputs (logits/q/mu)
 * ``make_act_fn(env_spec)``                  -> deterministic deployment
   policy ``act(qparams, obs)`` (argmax for discrete, tanh*scale for DDPG)
@@ -157,6 +161,27 @@ def make_actor_cache(params: Any, actor_backend: str, *,
     return qparams
 
 
+def repack_restored_cache(cache: Any, params: Any, actor_backend: str
+                          ) -> Any:
+    """A checkpointed cache, with byte-packed conv codes re-minted.
+
+    A checkpoint checks a packed leaf's shape and dtype, not its K order,
+    and byte-packed (``bits <= 4``) conv codes were once stored
+    channel-major ``(C_in*kh*kw, C_out)``.  Re-minting from ``params``,
+    the params the cache was packed from, gives the tap-major order
+    ``int8_conv2d`` reads, and the same codes for a cache already in it.
+    Conv caches never calibrate, so the re-mint is the whole cache.  Its
+    leaves are fresh buffers (a bias is otherwise the params' own), since
+    the cache rides a state that the training chunk donates.  Every other
+    cache is returned as restored.
+    """
+    if (cache == () or backend_bits(actor_backend) > 4
+            or not any(name.startswith("conv") for name in cache)):
+        return cache
+    return jax.tree_util.tree_map(
+        jnp.array, make_actor_cache(params, actor_backend))
+
+
 # ---------------------------------------------------------------------------
 # int8 layers
 # ---------------------------------------------------------------------------
@@ -205,48 +230,83 @@ def int8_dense(layer: Dict[str, Any], x: jnp.ndarray, *,
     return y.reshape(lead + (n,))
 
 
-def int8_conv2d(layer: Dict[str, Any], x: jnp.ndarray, stride: int = 1,
+def _conv_taps(xq: jnp.ndarray, zero: jnp.ndarray, hw, kernel_hw
+               ) -> jnp.ndarray:
+    """Tap-major GEMM operand of a stride-1 SAME conv over flat int8 rows.
+
+    ``xq`` is ``(N*H*W, C)`` int8 codes, row ``n*H*W + h*W + w``.  Tap
+    ``(i, j)`` reads the input at ``(h + dy, w + dx)``, ``(dy, dx) = (i -
+    (kh-1)//2, j - (kw-1)//2)``: the rows shifted by ``dy*W + dx``, with
+    ``zero`` (the code of 0.0) where the source lies outside the image.
+    The taps are concatenated along K, so feature ``(i*kw + j)*C + c``
+    matches HWIO weights reshaped to ``(kh*kw*C, C_out)``.
+    """
+    h, w = hw
+    kh, kw = kernel_hw
+    m = xq.shape[0]
+    py, px = (kh - 1) // 2, (kw - 1) // 2
+    lo, hi = py * w + px, (kh - 1 - py) * w + (kw - 1 - px)
+    padded = jnp.pad(xq, ((lo, hi), (0, 0)), constant_values=zero)
+    r = jnp.arange(m, dtype=jnp.int32)
+    col, row = r % w, (r // w) % h
+    taps = []
+    for dy in range(-py, kh - py):
+        for dx in range(-px, kw - px):
+            shift = lo + dy * w + dx
+            tap = padded[shift:shift + m]
+            inside = []
+            if dy:
+                inside.append((row + dy >= 0) & (row + dy < h))
+            if dx:
+                inside.append((col + dx >= 0) & (col + dx < w))
+            if inside:
+                ok = functools.reduce(jnp.logical_and, inside)
+                tap = jnp.where(ok[:, None], tap, zero)
+            taps.append(tap)
+    return jnp.concatenate(taps, axis=1)
+
+
+def int8_conv2d(layer: Dict[str, Any], x: jnp.ndarray, hw,
                 act: Callable = jax.nn.relu, *, backend: str = "auto"
                 ) -> jnp.ndarray:
-    """Conv through the W8A8 integer GEMM via an im2col patch extraction.
+    """Conv on flat rows through the W8A8 integer GEMM.
 
-    The conv weights are per-output-channel int8 codes; the input is lowered
-    to patches (``lax.conv_general_dilated_patches``, channel-major
-    ``(C_in, kh, kw)`` feature order) and the contraction runs through
-    ``kernels.ops.int8_matmul`` with the per-channel scales mapped onto the
-    kernel's per-column affine epilogue — true int8 compute, closing the
-    ROADMAP follow-up (previously the codes were dequantized in front of
-    ``lax.conv``).  Activations are dynamically quantized per-tensor over
-    the patch matrix, same policy as ``int8_dense``.
+    ``x`` is f32 ``(N*H*W, C_in)``, row ``n*H*W + h*W + w`` of images of
+    ``hw = (H, W)``; the result is ``(N*H*W, C_out)`` in the same row
+    order, which is the GEMM's own output layout, so convs chain without
+    a reshape.  The input is dynamically quantized per-tensor once (same
+    policy as ``int8_dense``; SAME zero padding adds only 0.0, which the
+    range already holds), and the GEMM operand is built tap-major from
+    the int8 codes (``_conv_taps``) against HWIO codes reshaped to
+    ``(kh*kw*C_in, C_out)``.  The per-channel weight scales sit in the
+    kernel's per-column affine epilogue.  Every network builds 3x3,
+    stride-1, SAME convs (``rl.networks.cnn_spec``), the one geometry
+    this runs; an unpacked fp32 kernel (e.g. a partially-packed tree)
+    runs a plain ``lax.conv`` of it.
     """
     w = layer["w"]
+    h, wd = hw
     if not isinstance(w, PackedTensor):
-        # unpacked fp32 conv (e.g. a partially-packed tree): plain compute
+        img = x.reshape(-1, h, wd, x.shape[-1])
         y = jax.lax.conv_general_dilated(
-            x, w.astype(x.dtype), window_strides=(stride, stride),
+            img, w.astype(x.dtype), window_strides=(1, 1),
             padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        y = y + layer["b"].astype(x.dtype)
+        y = y.reshape(-1, y.shape[-1]) + layer["b"].astype(x.dtype)
         return act(y) if act is not None else y
     kh, kw, c_in, c_out = (w.orig_shape if w.orig_shape is not None
                            else w.codes.shape)
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    lead = patches.shape[:-1]
-    p2 = patches.reshape(-1, patches.shape[-1])
-    pq, pp = affine.quantize_to_int(p2, 8)
-    if w.orig_shape is not None:
-        # sub-8-bit conv codes are pre-transposed to the im2col layout and
-        # byte-packed at pack time; the GEMM unpacks in-kernel
-        w2 = w.codes
-    else:
-        # patches order features as (C_in, kh, kw); permute HWIO codes
-        w2 = jnp.transpose(w.codes, (2, 0, 1, 3)).reshape(-1, c_out)
+    xq, xp = affine.quantize_to_int(x, 8)
+    zero = affine.quantize_with_params(jnp.zeros((), x.dtype), xp)
+    patches = _conv_taps(xq, zero, hw, (kh, kw))
+    # sub-8-bit codes are byte-packed in this (kh*kw*C_in, C_out) layout
+    # at pack time; the GEMM unpacks them in-kernel
+    w2 = (w.codes if w.orig_shape is not None
+          else w.codes.reshape(kh * kw * c_in, c_out))
     w_scale, w_zero = _col_arrays(w, c_out)
-    y = ops.int8_matmul(pq, w2, pp.delta, pp.zero_point, w_scale, w_zero,
-                        backend=backend,
+    y = ops.int8_matmul(patches, w2, xp.delta, xp.zero_point, w_scale,
+                        w_zero, backend=backend,
                         w_bits=w.bits if w.bits <= 4 else 8)
-    y = y.reshape(lead + (c_out,)) + layer["b"].astype(y.dtype)
+    y = y + layer["b"].astype(y.dtype)
     if act is not None:
         y = act(y)
     return y
@@ -307,16 +367,19 @@ def quantized_cnn_apply(qparams: QuantizedParams, x: jnp.ndarray,
                         ) -> jnp.ndarray:
     """CNN head outputs from a packed cache (per-layer int8 path).
 
-    ``x`` is f32 ``(*batch, H, W, C)`` — any leading batch dims are
-    flattened for the im2col int8 convs and restored on the ``(*batch,
-    head_dim)`` f32 result.  Conv caches never calibrate, so this is
-    always the per-layer dynamic-quantization path.
+    ``x`` is f32 ``(*batch, H, W, C)`` — the convs run on flat ``(rows,
+    C)`` activations (``int8_conv2d``) and the last one's rows flatten to
+    ``(batch, H*W*C)`` for the FC, the ``(h, w, c)`` order of
+    ``rl.networks.cnn_apply``; the ``(*batch, head_dim)`` f32 result
+    restores the leading batch dims.  Conv caches never calibrate, so
+    this is always the per-layer dynamic-quantization path.
     """
     batch_shape = x.shape[:-3]
-    x = x.reshape((-1,) + x.shape[-3:])
+    h, w, c = x.shape[-3:]
+    x = x.reshape(-1, c)
     for i in range(n_convs):
-        x = int8_conv2d(qparams[f"conv{i}"], x, backend=backend)
-    x = x.reshape(x.shape[0], -1)
+        x = int8_conv2d(qparams[f"conv{i}"], x, (h, w), backend=backend)
+    x = x.reshape(-1, h * w * x.shape[-1])
     x = int8_dense(qparams["fc"], x, backend=backend, act=jax.nn.relu)
     y = int8_dense(qparams["out"], x, backend=backend)
     return y.reshape(batch_shape + y.shape[-1:])

@@ -378,6 +378,11 @@ def train(algo: str, env_name: str, *, iterations: int = 200,
                         "obs": obs, "key": k_run})
             state, env_state, obs, k_run = (
                 tree["state"], tree["env_state"], tree["obs"], tree["key"])
+            if isinstance(state, actor_learner.ActorLearnerState):
+                state = actor_learner.with_cache(
+                    state, actorq.repack_restored_cache(
+                        state.actor_cache, state.actor_params,
+                        actor_backend))
             i = int(extra["iteration"])
             rewards = [float(r) for r in extra["rewards"]]
             variances = [float(v) for v in extra["action_variances"]]
@@ -626,6 +631,8 @@ def _train_async(algo, env, net, cfg, *, iterations, record_every,
             learner, wbuf, env_state, obs, snap, k_run = (
                 tree["learner"], tree["wbuf"], tree["env_state"],
                 tree["obs"], tree["snap"], tree["key"])
+            snap = snap._replace(cache=actorq.repack_restored_cache(
+                snap.cache, snap.params, actor_backend))
             i = int(extra["iteration"])
             rewards = [float(r) for r in extra["rewards"]]
             variances = [float(v) for v in extra["action_variances"]]

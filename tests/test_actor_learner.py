@@ -3,8 +3,9 @@
 * parity — a single-actor actor–learner run with ``sync_every=1`` is
   bitwise identical to the fused ``loops.train`` driver for DQN (same
   seeds -> same params, same recorded rewards),
-* int8 conv compute (im2col through the W8A8 kernel) agrees with the
-  fake-quant conv simulation within the ``test_actorq.py`` tolerance,
+* int8 conv compute (tap-major patches through the W8A8 kernel) agrees
+  with the fake-quant conv simulation within the ``test_actorq.py``
+  tolerance,
 * the sharded replay layout round-trips,
 * DDPG/PPO rollout collection accepts ``actor_backend="int8"`` and stays
   finite on the smoke envs,
@@ -272,7 +273,7 @@ def test_actor_learner_eight_device_mesh():
 
 
 # ---------------------------------------------------------------------------
-# int8 conv compute (im2col through the W8A8 kernel)
+# int8 conv compute (tap-major patches through the W8A8 kernel)
 # ---------------------------------------------------------------------------
 
 def _fake_quant_outputs(net, params, obs):
@@ -308,11 +309,11 @@ def test_int8_conv_unpacked_weights_fall_back_to_fp32():
     layer = {"w": jax.random.normal(jax.random.PRNGKey(0), (3, 3, 2, 4)),
              "b": jnp.zeros((4,))}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 6, 2))
-    y = actorq.int8_conv2d(layer, x, backend="ref")
+    y = actorq.int8_conv2d(layer, x.reshape(-1, 2), (6, 6), backend="ref")
     want = jax.nn.relu(jax.lax.conv_general_dilated(
         x, layer["w"], (1, 1), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC")))
-    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(y, want.reshape(-1, 4), rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -11,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import ptq
+from repro.core import affine, ptq
 from repro.core.fake_quant import NullQATContext
 from repro.core.qconfig import QuantConfig
+from repro.kernels import ops
 from repro.rl import actorq, loops
 from repro.rl.envs import make as make_env
 from repro.rl.networks import make_network
@@ -58,6 +59,63 @@ def test_int8_interpret_kernel_matches_ref_oracle():
     ref = actorq.quantized_apply(qp, obs, backend="ref")
     interp = actorq.quantized_apply(qp, obs, backend="interpret")
     np.testing.assert_allclose(interp, ref, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# int8 conv: tap-major operand vs the channel-major im2col lowering
+# ---------------------------------------------------------------------------
+
+def _im2col_conv(layer, x, backend):
+    """The channel-major im2col lowering, as the oracle: f32 patches in
+    ``(C_in, kh, kw)`` feature order, quantized per-tensor, against HWIO
+    codes transposed to that order, through the same GEMM."""
+    w = layer["w"]
+    kh, kw, c_in, c_out = w.codes.shape
+    patches = jax.lax.conv_general_dilated_patches(
+        x, (kh, kw), (1, 1), padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    pq, pp = affine.quantize_to_int(
+        patches.reshape(-1, patches.shape[-1]), 8)
+    w2 = jnp.transpose(w.codes, (2, 0, 1, 3)).reshape(-1, c_out)
+    y = ops.int8_matmul(pq, w2, pp.delta, pp.zero_point, w.col_scale,
+                        w.col_zero, backend=backend)
+    return jax.nn.relu(y + layer["b"])
+
+
+_CONV_CASES = {
+    # name: (batch dims, H, W, C_in, sign of the input)
+    "c1": ((4,), 6, 4, 1, 0),
+    "c2_lead_dims": ((2, 3), 3, 5, 2, 0),
+    "c128": ((2,), 4, 3, 128, 0),
+    "c2_nonneg": ((3,), 5, 4, 2, 1),
+    # all non-positive: z = 256 clips to code 127, so the pad code is not
+    # the stored zero point
+    "c2_nonpos": ((3,), 4, 5, 2, -1),
+}
+
+
+@pytest.mark.parametrize("backend", ["ref", "xla", "interpret"])
+@pytest.mark.parametrize("case", sorted(_CONV_CASES))
+def test_int8_conv_tap_major_matches_im2col(case, backend):
+    batch, h, w, c_in, sign = _CONV_CASES[case]
+    kw_, kx, kb = jax.random.split(jax.random.PRNGKey(c_in + h), 3)
+    layer = ptq.ptq_pack(
+        {"w": jax.random.normal(kw_, (3, 3, c_in, 8)),
+         "b": jax.random.normal(kb, (8,)) * 0.1}, QuantConfig.ptq_int(8))
+    x = jax.random.normal(kx, batch + (h, w, c_in)) * 2.0
+    if sign:
+        x = sign * jnp.abs(x)
+    if sign < 0:
+        p = affine.calibration_params(x, 8)
+        assert float(p.zero_point) > 127.0     # the clipping case
+    got = actorq.int8_conv2d(layer, x.reshape(-1, c_in), (h, w),
+                             backend=backend)
+    want = _im2col_conv(layer, x.reshape((-1, h, w, c_in)), backend)
+    assert got.shape == (want.shape[0], 8)
+    if backend == "interpret":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_packed_actor_is_4x_smaller():

@@ -10,7 +10,7 @@
   learner-update staleness contract,
 * the double-buffer layout itself: independent slots, host-level swap,
   capacity conservation,
-* the pixel (Catch) envs run the conv int8 im2col path under async
+* the pixel (Catch) envs run the conv int8 path under async
   fan-out (fast smoke + slow convergence),
 * a 4-device mesh smoke run (slow, subprocess) drives both async
   programs through shard_map.
@@ -135,7 +135,7 @@ def test_async_learner_consumes_double_buffered_data():
 
 
 def test_async_catch_pixel_smoke():
-    # the conv int8 im2col path under async fan-out (fast finiteness
+    # the conv int8 path under async fan-out (fast finiteness
     # smoke; convergence is the slow test below)
     res = loops.train("dqn", "catch", topology="async", num_actors=2,
                       sync_every=4, steps_per_call=2, actor_backend="int8",
@@ -215,7 +215,7 @@ def test_double_buffer_swap_is_reference_exchange():
 @pytest.mark.slow
 def test_async_int8_catch_convergence():
     """ISSUE acceptance: async int8 fan-out learns sparse-reward Catch —
-    the conv im2col int8 path under true overlapped collection."""
+    the conv int8 path under true overlapped collection."""
     cfg = dict(n_envs=8, rollout_steps=8, updates_per_iter=4,
                buffer_size=8192, batch_size=32, warmup=256,
                eps_decay_updates=800, target_update_every=100)

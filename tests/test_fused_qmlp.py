@@ -239,7 +239,7 @@ def test_int4_async_topology_with_calibration():
 
 
 def test_int4_catch_conv_smoke():
-    """Pixel env: the conv im2col GEMM consumes byte-packed int4 codes."""
+    """Pixel env: the conv GEMM consumes byte-packed int4 codes."""
     res = loops.train("dqn", "catch", iterations=2, record_every=2,
                       eval_episodes=2, actor_backend="int4",
                       net_kwargs=dict(conv_filters=(4,), fc_width=16),

@@ -37,11 +37,11 @@ def _leaves(tree):
 
 
 def _train(algo, env, topo, *, ckpt_dir=None, iterations=6, resume=False,
-           net_kwargs=None, **kw):
+           net_kwargs=None, actor_backend="int8", **kw):
     multi = topo != "fused"
     return loops.train(
         algo, env, iterations=iterations, seed=3, record_every=3,
-        eval_episodes=2, actor_backend="int8",
+        eval_episodes=2, actor_backend=actor_backend,
         algo_overrides=dict(SMALL),
         net_kwargs=net_kwargs or dict(hidden=(16,)),
         topology=topo, num_actors=2 if multi else 1,
@@ -96,6 +96,20 @@ def test_resume_bitwise_seq_policy(tmp_path, topo):
     full = _train("dqn", "catch_seq", topo, **kw)
     _train("dqn", "catch_seq", topo, ckpt_dir=d, iterations=3, **kw)
     res = _train("dqn", "catch_seq", topo, ckpt_dir=d, resume=True, **kw)
+    _assert_bitwise(full, res)
+
+
+@pytest.mark.parametrize("topo", ["actor-learner", "async"])
+def test_resume_bitwise_int4_conv_cache(tmp_path, topo):
+    """A byte-packed (W4A8) conv cache in the state is re-minted from its
+    actor params on restore, and the resumed run stays bitwise on the
+    uninterrupted one."""
+    d = str(tmp_path / "ckpt")
+    kw = dict(actor_backend="int4",
+              net_kwargs=dict(conv_filters=(4,), fc_width=16))
+    full = _train("dqn", "catch", topo, **kw)
+    _train("dqn", "catch", topo, ckpt_dir=d, iterations=3, **kw)
+    res = _train("dqn", "catch", topo, ckpt_dir=d, resume=True, **kw)
     _assert_bitwise(full, res)
 
 

@@ -14,6 +14,7 @@ and every pytest-xdist worker imports every test file.  Keep all such
 compiles in this one file, so that one worker holds the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +119,25 @@ def test_flash_attention_compiles(one_chip):
     q = jax.ShapeDtypeStruct((8, 256, 128), jnp.float32)
     _compile(lambda q, k, v: ops.flash_attention(q, k, v, backend="pallas"),
              *_on(one_chip, (q, q, q)))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_int8_conv2d_policy_c_compiles_tap_major(one_chip, bits):
+    """Policy C's 1024-channel conv over 64 observations of 10x10, with
+    int8 (W8A8) and byte-packed int4 (W4A8) weight codes: the GEMM
+    operand is the dense tap-major ``s8[6400, 9216]``, built from the
+    int8 codes with no lane-padded ``[..., 9]`` patch array, no patch
+    convolution and at most twice that operand in temporaries."""
+    n, h, w, c = 64, 10, 10, 1024
+    layer = jax.eval_shape(lambda: actorq.pack_actor_params(
+        {"w": jnp.zeros((3, 3, c, c)), "b": jnp.zeros((c,))}, bits))
+    x = jax.ShapeDtypeStruct((n * h * w, c), jnp.float32)
+    compiled = _compile(
+        lambda lay, x: actorq.int8_conv2d(lay, x, (h, w), backend="pallas"),
+        *_on(one_chip, (layer, x)))
+    text = compiled.as_text()
+    assert not re.search(r"s8\[[0-9,]*,9\]", text)
+    assert "convolution(" not in text
+    assert "conv_general_dilated_patches" not in text
+    operand = n * h * w * 9 * c
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * operand

@@ -5,7 +5,8 @@ Acceptance contracts:
   odd/even K (the int4 padding edge) x chunked K (contractions longer than
   the exact-f32 bound, exercising the int32 chunk accumulator),
 * the same parity for the per-layer and fused actor applies across heads
-  {logits, q, mu} and for the conv im2col path (Catch pixel actors),
+  {logits, q, mu} and for the conv path (Catch pixel actors, tap-major
+  int8 patches),
 * ``_resolve``: ``auto`` -> ``xla`` off-TPU, the ``REPRO_KERNEL_BACKEND``
   env override, and explicit ``backend=`` always winning,
 * the 8-bit branch rejects K-mismatched weights with a ``ValueError``
@@ -17,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import affine
+from repro.core import affine, ptq
+from repro.core.qconfig import QuantConfig
 from repro.kernels import ops, ref, xla_backend
 from repro.rl import actorq, loops
 from repro.rl.networks import make_network
@@ -104,6 +106,73 @@ def test_conv_im2col_xla_bitwise_matches_ref(bits):
     np.testing.assert_array_equal(
         np.asarray(actorq.quantized_apply(qp, obs, backend="xla")),
         np.asarray(actorq.quantized_apply(qp, obs, backend="ref")))
+
+
+@pytest.mark.parametrize("backend", ["ref", "xla", "interpret"])
+def test_int4_conv_cache_round_trips(backend):
+    """A packed int4 conv weight is HWIO reshaped tap-major: dequantize
+    restores HWIO, and the W4A8 conv equals the W8A8 conv over the
+    unpacked codes."""
+    w = jax.random.normal(jax.random.PRNGKey(5), (3, 3, 3, 8))
+    packed = ptq.ptq_pack({"w": w}, QuantConfig.ptq_int(4))["w"]
+    codes, p = affine.quantize_to_int(w, 4, axis=3)
+    assert packed.codes.shape == ((3 * 3 * 3 + 1) // 2, 8)
+    np.testing.assert_array_equal(
+        np.asarray(packed.unpacked_codes().reshape(w.shape)),
+        np.asarray(codes))
+    np.testing.assert_array_equal(
+        np.asarray(packed.dequantize()),
+        np.asarray(affine.dequantize_from_int(codes, p)))
+    # the same codes unpacked, in HWIO, through the 8-bit GEMM path
+    w8 = packed._replace(codes=codes, bits=8, orig_shape=None)
+    b = jnp.linspace(-0.1, 0.1, 8)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2 * 5 * 4, 3))
+    got = actorq.int8_conv2d({"w": packed, "b": b}, x, (5, 4),
+                             backend=backend)
+    want = actorq.int8_conv2d({"w": w8, "b": b}, x, (5, 4), backend=backend)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _channel_major(tensor):
+    """A packed int4 conv weight with its codes in the channel-major
+    ``(C_in*kh*kw, C_out)`` K order, as a checkpoint may hold them."""
+    kh, kw, ci, co = tensor.orig_shape
+    codes = tensor.unpacked_codes().reshape(kh, kw, ci, co)
+    codes = codes.transpose(2, 0, 1, 3).reshape(-1, co)
+    return tensor._replace(codes=affine.pack_int4(codes))
+
+
+@pytest.mark.parametrize("actor_backend", ["int4", "int8"])
+def test_restored_conv_cache_is_repacked_tap_major(actor_backend):
+    """A restored int4 conv cache is re-minted from its params: codes in
+    the channel-major order come back tap-major, and a cache already in
+    it comes back the same.  An int8 cache, whose order never changed,
+    and an MLP cache are returned as restored."""
+    net = make_network((6, 5, 1), 3, conv_filters=(4, 8), fc_width=16)
+    params = net.init(jax.random.PRNGKey(8))
+    fresh = actorq.make_actor_cache(params, actor_backend)
+    obs = jax.random.normal(jax.random.PRNGKey(9), (3, 6, 5, 1))
+    want = actorq.quantized_apply(fresh, obs, backend="ref")
+    if actor_backend == "int8":
+        assert actorq.repack_restored_cache(fresh, params, "int8") is fresh
+        mlp = make_network((5,), 3, hidden=(8,)).init(jax.random.PRNGKey(1))
+        mlp_cache = actorq.make_actor_cache(mlp, "int4")
+        assert actorq.repack_restored_cache(mlp_cache, mlp,
+                                            "int4") is mlp_cache
+        return
+    old = {name: ({**layer, "w": _channel_major(layer["w"])}
+                  if name.startswith("conv") else layer)
+           for name, layer in fresh.items()}
+    assert not np.array_equal(np.asarray(old["conv1"]["w"].codes),
+                              np.asarray(fresh["conv1"]["w"].codes))
+    for restored in (old, fresh):
+        got = actorq.repack_restored_cache(restored, params, "int4")
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(fresh)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(actorq.quantized_apply(got, obs, backend="ref")),
+            np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
