@@ -18,9 +18,9 @@ their rows:
   ``calib_batch``, recalibrated) from the learner's parameters.
 
 Once the window has closed and the program's state is freed, ``judge``
-runs the reference (``reference.py``) over the same rows from the seed's
-weights and returns the numbers that ``compare`` holds to the cell's
-limits:
+runs the reference (``reference.py``, with the policy kind's forward) over
+the same rows from the seed's weights and returns the numbers that
+``compare`` holds to the cell's limits:
 
 * ``opt_steps``: Adam steps taken minus updates run (exact);
 * ``loss_gap``: worst of the first three iterations' relative loss gap;
@@ -73,12 +73,12 @@ def _program_norms(weights_fn):
     return norms
 
 
-def collect(driver, state, losses, k_run, k_weights, weights_fn, seed: int,
+def collect(driver, state, losses, k_run, k_weights, seed: int,
             traffic: Dict) -> Dict:
     """Read what the set-up's first ``check_iters`` iterations produced
     (see the module docstring); ``losses`` are their chunks' losses."""
     params, m, step, replay = driver.learner_view(state)
-    m_norm, dp_norm = _program_norms(weights_fn)(
+    m_norm, dp_norm = _program_norms(driver.policy.init)(
         driver.from_program(params), driver.from_program(m), k_weights)
     batches, sizes = [], []
     for shards, pos, total in driver.sampled_rows(k_run,
@@ -110,21 +110,19 @@ def collect(driver, state, losses, k_run, k_weights, weights_fn, seed: int,
     }
 
 
-def first_iterations(driver, weights_fn, seed: int, traffic: Dict,
-                     key_of):
+def first_iterations(driver, seed: int, traffic: Dict, key_of):
     """A run's set-up up to the check: the seed's fresh carry driven
     through ``check_iters`` iterations by the cell's own chunk, read by
     ``collect``.  Returns ``((state, env_state, obs, key), stash)``."""
     k_weights, k_init, k_env, k_run = (key_of(seed, s) for s in range(4))
-    state, env_state, obs = driver.init(weights_fn, k_weights, k_init,
-                                        k_env)
+    state, env_state, obs = driver.init(k_weights, k_init, k_env)
     key, losses = k_run, []
     for _ in range(traffic["check_iters"] // traffic["steps_per_call"]):
         state, env_state, obs, key, metrics = driver.chunk(state, env_state,
                                                            obs, key)
         losses.append(driver.losses(metrics))
-    stash = collect(driver, state, losses, k_run, k_weights, weights_fn,
-                    seed, traffic)
+    stash = collect(driver, state, losses, k_run, k_weights, seed,
+                    traffic)
     return (state, env_state, obs, key), stash
 
 
@@ -135,17 +133,16 @@ def _leaf_gap(prog: np.ndarray, refn: np.ndarray, keep: np.ndarray) -> float:
     return float(np.max(gaps[keep])) if keep.any() else 0.0
 
 
-def reference_run(stash: Dict, config: Dict, traffic: Dict, w0,
+def reference_run(stash: Dict, policy, traffic: Dict, w0,
                   dtype=jnp.float32, batches=None) -> Dict:
     """The reference learner over the stashed rows (or ``batches``): its
     per-iteration losses and, per leaf, the norms of Adam's first moment and
     of the parameter change, in the layout ``collect`` reads the program's
     in, and its parameters as the first push carried them (``pushed``)."""
-    layers = ref.layers_of(config)
-    hp = dict(config["learner"], warmup=traffic["warmup"])
+    hp = dict(policy.config["learner"], warmup=traffic["warmup"])
     batches = stash["batches"] if batches is None else batches
     losses, st, pushed = ref.follow(
-        layers, hp, w0, batches, stash["sizes"], dtype=dtype,
+        policy.forward, hp, w0, batches, stash["sizes"], dtype=dtype,
         keep_after=traffic["sync_every"] * traffic["updates_per_iter"])
     f32 = functools.partial(jax.tree_util.tree_map,
                             lambda x: x.astype(jnp.float32))
@@ -173,44 +170,44 @@ def learner_numbers(prog: Dict, refr: Dict, n_updates: int
     }
 
 
-def action_gap(layers, w0, obs: np.ndarray, action: np.ndarray) -> float:
+def action_gap(forward, w0, obs: np.ndarray, action: np.ndarray) -> float:
     """Widest gap of a chosen action's reference Q below the best, over
     the median of the largest |Q|."""
-    q = ref.q_values(layers, w0, obs)
+    q = ref.q_values(forward, w0, obs)
     best = q.max(axis=1)
     chosen = q[np.arange(len(action)), action.astype(np.int64)]
     scale = float(np.median(np.abs(q).max(axis=1)))
     return float(np.max(best - chosen) / max(scale, 1e-30))
 
 
-def actor_numbers(layers, w0, pushed, stash: Dict, acted=None,
+def actor_numbers(forward, w0, pushed, stash: Dict, acted=None,
                   pushed_acted=None) -> Dict[str, float]:
     """``action_gap`` and ``push_action_gap`` of the stashed actions (or
-    of ``acted`` and ``pushed_acted`` put in their place)."""
+    of ``acted`` and ``pushed_acted`` put in their place), by the
+    reference ``forward``."""
     return {
         "action_gap": action_gap(
-            layers, w0, stash["acted_obs"],
+            forward, w0, stash["acted_obs"],
             stash["acted_action"] if acted is None else acted),
         "push_action_gap": action_gap(
-            layers, pushed, stash["pushed_obs"],
+            forward, pushed, stash["pushed_obs"],
             stash["pushed_action"] if pushed_acted is None
             else pushed_acted),
     }
 
 
-def seed_weights(config: Dict, k_weights):
+def seed_weights(policy, k_weights):
     """The seed's float32 weights, made on the device in one call."""
-    return jax.jit(weights_fn_for(config))(k_weights)
+    return jax.jit(policy.init)(k_weights)
 
 
-def judge(stash: Dict, config: Dict, traffic: Dict, k_weights
+def judge(stash: Dict, policy, traffic: Dict, k_weights
           ) -> Dict[str, float]:
     """Run the reference over the stashed rows; the cell's numbers."""
-    w0 = seed_weights(config, k_weights)
-    refr = reference_run(stash, config, traffic, w0)
+    w0 = seed_weights(policy, k_weights)
+    refr = reference_run(stash, policy, traffic, w0)
     out = learner_numbers(stash, refr, len(stash["batches"]))
-    out.update(actor_numbers(ref.layers_of(config), w0, refr["pushed"],
-                             stash))
+    out.update(actor_numbers(policy.forward, w0, refr["pushed"], stash))
     return out
 
 
@@ -221,11 +218,4 @@ def compare(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
         raise KeyError(f"no reading for {missing}")
     return all(np.isfinite(numbers[k]) and numbers[k] <= limits[k]
                for k in limits)
-
-
-def weights_fn_for(config: Dict):
-    """The seed's weights as a jit-friendly function of a key."""
-    layers = tuple(ref.layers_of(config))
-    return functools.partial(ref.init_weights, layers=layers)
-
 

@@ -47,40 +47,39 @@ def _readings(drv, spec, seed, mesh_devices):
     import check
     import reference as ref
 
-    config, traffic = spec["config"], spec["traffic"]
-    wf = check.weights_fn_for(config)
+    policy, traffic = spec["policy"], spec["traffic"]
     k_w = run.key_of(seed, 0)
-    _, stash = check.first_iterations(drv, wf, seed, traffic, run.key_of)
-    w0 = check.seed_weights(config, k_w)
-    layers = ref.layers_of(config)
+    _, stash = check.first_iterations(drv, seed, traffic, run.key_of)
+    w0 = check.seed_weights(policy, k_w)
     n_up = len(stash["batches"])
-    refr = check.reference_run(stash, config, traffic, w0)
+    refr = check.reference_run(stash, policy, traffic, w0)
 
     def numbers(prog, acted=None, pushed_acted=None):
         out = check.learner_numbers(prog, refr, n_up)
-        out.update(check.actor_numbers(layers, w0, refr["pushed"], stash,
-                                       acted, pushed_acted))
+        out.update(check.actor_numbers(policy.forward, w0, refr["pushed"],
+                                       stash, acted, pushed_acted))
         return out
 
     out = {"seed": seed, "program": numbers(stash)}
-    ctl = check.reference_run(stash, config, traffic, w0,
+    ctl = check.reference_run(stash, policy, traffic, w0,
                               dtype=jnp.bfloat16)
     out["control_bf16"] = numbers(ctl)
     half = [{k: v[: len(v) // 2] for k, v in b.items()}
             for b in stash["batches"]]
     out["fault_half_batch"] = numbers(
-        check.reference_run(stash, config, traffic, w0, batches=half))
+        check.reference_run(stash, policy, traffic, w0, batches=half))
     if mesh_devices > 1:
         quarter = [{k: v[: len(v) // mesh_devices] for k, v in b.items()}
                    for b in stash["batches"]]
         out["fault_no_exchange"] = numbers(
-            check.reference_run(stash, config, traffic, w0,
+            check.reference_run(stash, policy, traffic, w0,
                                 batches=quarter))
-    n_actions = config["env"]["n_actions"]
+    n_actions = policy.config["env"]["n_actions"]
     out["fault_action"] = numbers(
         stash, acted=(stash["acted_action"] + 1) % n_actions,
         pushed_acted=(stash["pushed_action"] + 1) % n_actions)
-    stale = ref.q_values(layers, w0, stash["pushed_obs"]).argmax(axis=1)
+    stale = ref.q_values(policy.forward, w0,
+                         stash["pushed_obs"]).argmax(axis=1)
     out["fault_push_skipped"] = numbers(stash, pushed_acted=stale)
     return out
 
@@ -96,7 +95,6 @@ def main(argv=None):
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     import check
-    import reference as ref
     from drive_actor_learner import ActorLearnerDriver
 
     spec = run.load_cell(args.workload)
@@ -116,21 +114,20 @@ def main(argv=None):
                 sink.write(line + "\n")
                 sink.flush()
 
-        drv = ActorLearnerDriver(spec["config"], spec["traffic"], mesh)
+        drv = ActorLearnerDriver(spec["policy"], spec["traffic"], mesh)
         for seed in args.seeds:
             emit(_readings(drv, spec, seed, chips))
         if args.int4:
-            cfg4 = dict(spec["config"], actor_backend="int4")
-            drv4 = ActorLearnerDriver(cfg4, spec["traffic"], mesh)
-            wf = check.weights_fn_for(cfg4)
+            pol4 = spec["policy"].with_config(actor_backend="int4")
+            drv4 = ActorLearnerDriver(pol4, spec["traffic"], mesh)
             for seed in args.seeds[: args.int4]:
-                _, stash = check.first_iterations(drv4, wf, seed,
+                _, stash = check.first_iterations(drv4, seed,
                                                   spec["traffic"],
                                                   run.key_of)
-                w0 = check.seed_weights(cfg4, run.key_of(seed, 0))
-                refr = check.reference_run(stash, cfg4, spec["traffic"], w0)
+                w0 = check.seed_weights(pol4, run.key_of(seed, 0))
+                refr = check.reference_run(stash, pol4, spec["traffic"], w0)
                 emit({"seed": seed, "control_int4": check.actor_numbers(
-                    ref.layers_of(cfg4), w0, refr["pushed"], stash)})
+                    pol4.forward, w0, refr["pushed"], stash)})
 
 if __name__ == "__main__":
     main()

@@ -12,8 +12,16 @@ file lists the surface a refactor of ``rl/loops.py`` and
   chunk ``loops.train(topology="actor-learner")`` dispatches: one jitted
   scan with donated ``(state, env_state, obs)``;
 * ``rl.dqn.DQNConfig`` and ``optim.adam.AdamConfig`` carry the learner's
-  settings, ``configs.quarl_atari`` the paper's widths, ``rl.envs.make``
-  and ``rl.networks.make_network`` the environment and the policy.
+  settings, ``configs.quarl_atari`` the paper's widths (the constant a
+  configuration's ``policy.constant`` names), ``rl.envs.make`` (with the
+  configuration's ``env.kwargs``) and ``rl.networks.make_network`` the
+  environment and the policy.
+
+The policy itself is its kind's (``policies/<kind>.py``, bound in
+``kinds.Policy``): the kind turns the configuration into
+``make_network``'s keyword arguments, checked against that constant, and
+maps the benchmark's weights to the program's parameter tree and back
+(``to_program``, ``from_program``).  Nothing here names a kind.
 
 Two things here mirror the program rather than call it, and are checked
 against it by the benchmark's correctness test on the CPU:
@@ -32,7 +40,7 @@ import copy
 import dataclasses
 import os
 import sys
-from typing import Dict, List
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -52,31 +60,25 @@ from repro.rl.networks import make_network  # noqa: E402
 AXIS = "actor"
 
 
-def _program_widths(policy: Dict):
-    const = getattr(quarl_atari, policy["constant"])
-    if policy["kind"] == "conv":
-        got = {"conv_filters": list(const.conv_filters),
-               "fc_width": const.fc_width}
-        want = {"conv_filters": policy["conv_filters"],
-                "fc_width": policy["fc_width"]}
-        kwargs = dict(conv_filters=tuple(const.conv_filters),
-                      fc_width=const.fc_width)
-    else:
-        got, want = list(const.widths), policy["widths"]
-        kwargs = dict(hidden=tuple(const.widths))
-    if got != want:
-        raise ValueError(f"quarl_atari.{policy['constant']} is {got}, the "
-                         f"configuration file says {want}")
-    return kwargs
+def network_kwargs(policy) -> Dict:
+    """``make_network``'s keyword arguments for a bound policy, checked by
+    its kind against the ``quarl_atari`` constant the configuration names
+    (None where it names none)."""
+    name = policy.config["policy"].get("constant")
+    constant = getattr(quarl_atari, name) if name else None
+    return policy.kind.network_kwargs(policy.config, constant)
 
 
 class ActorLearnerDriver:
     """The actor-learner chunk of one cell, built from its configuration
-    and traffic files."""
+    (bound to its kind, ``kinds.Policy``) and traffic files."""
 
-    def __init__(self, config: Dict, traffic: Dict, mesh=None):
-        self.config, self.traffic, self.mesh = config, traffic, mesh
-        env = make_env(config["env"]["name"])
+    def __init__(self, policy, traffic: Dict, mesh=None):
+        config = policy.config
+        self.policy, self.config = policy, config
+        self.traffic, self.mesh = traffic, mesh
+        env = make_env(config["env"]["name"],
+                       **config["env"].get("kwargs", {}))
         if (list(env.spec.obs_shape) != config["env"]["obs_shape"]
                 or env.spec.n_actions != config["env"]["n_actions"]):
             raise ValueError(f"env {env.spec} does not match the "
@@ -90,7 +92,7 @@ class ActorLearnerDriver:
                                  f"file says {lrn[k]}")
         self.env = env
         self.net = make_network(env.spec.obs_shape, env.spec.n_actions,
-                                **_program_widths(config["policy"]))
+                                **network_kwargs(policy))
         t = traffic
         if t["topology"] != "actor-learner":
             raise ValueError(f"this adapter drives topology='actor-learner', "
@@ -116,16 +118,9 @@ class ActorLearnerDriver:
         self.capacity = t["buffer_size"] // t["num_actors"]     # per shard
 
     # -- weights ---------------------------------------------------------
-    def _layer_names(self) -> List[str]:
-        pol = self.config["policy"]
-        if pol["kind"] == "conv":
-            return [f"conv{i}" for i in range(len(pol["conv_filters"]))] \
-                + ["fc", "out"]
-        return [f"fc{i}" for i in range(len(pol["widths"]))] + ["out"]
-
-    def to_program(self, weights: List[Dict]) -> Dict:
-        """The benchmark's layer list as the program's parameter tree."""
-        tree = dict(zip(self._layer_names(), weights))
+    def to_program(self, weights) -> Dict:
+        """The benchmark's weights as the program's parameter tree."""
+        tree = self.policy.kind.to_program(weights, self.config)
         want = jax.eval_shape(self.net.init, jax.random.PRNGKey(0))
         got = jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), tree)
         if got != jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), want):
@@ -133,18 +128,18 @@ class ActorLearnerDriver:
                              f"network {want}")
         return tree
 
-    def from_program(self, tree: Dict) -> List:
-        """The program's parameter tree as the benchmark's layer list."""
-        return [tree[n] for n in self._layer_names()]
+    def from_program(self, tree: Dict):
+        """The program's parameter tree as the benchmark's weights."""
+        return self.policy.kind.from_program(tree, self.config)
 
     # -- the carry ---------------------------------------------------------
-    def init(self, weights_fn, k_weights, k_init, k_env):
-        """``(state, env_state, obs)`` in one jitted call: the benchmark's
-        weights from ``weights_fn(k_weights)`` become the learner's, the
+    def init(self, k_weights, k_init, k_env):
+        """``(state, env_state, obs)`` in one jitted call: the seed's
+        weights (``policy.init(k_weights)``) become the learner's, the
         target's and the actors' parameters (``actor_learner.init`` packs
         the actors' int8 cache from them)."""
         def build(k_weights, k_init, k_env):
-            params = self.to_program(weights_fn(k_weights))
+            params = self.to_program(self.policy.init(k_weights))
             net = copy.copy(self.net)
             net.init = lambda key, dtype=jnp.float32: params
             state = actor_learner.init(k_init, self.env, net, "dqn",

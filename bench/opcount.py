@@ -3,48 +3,34 @@
 Counts follow the algorithm, not the program's lowering: a GEMM of
 ``(M, K) @ (K, N)`` is ``2 M K N`` operations, and the least bytes it
 moves are its operands once and its result once.  For the int8 kernels
-(``kernels/int8_matmul.py``, ``kernels/fused_qmlp.py``) the operands are
-int8 and the result is float32.  A conv is counted as the GEMM its im2col
-lowering runs: ``M = batch * H * W`` rows of ``K = 9 * C_in``.
+the operands are int8 and the result is float32.  What a policy runs, and
+through which kernels, is its kind's to count (``policies/<kind>.py``);
+the helpers here are the arithmetic the kinds share.
 
 ``work`` gives one chip's share of a run of iterations:
 
-* the actors' forward passes: ``rollout_steps`` per iteration, over the
-  chip's ``local_actors * n_envs`` environments, plus at each parameter
-  push the divergence head over the same observations (int8 and float32)
-  and, for a calibrated MLP cache, the calibration pass over
-  ``calib_batch`` observations through per-layer ``int8_matmul``;
-* the learner: per update, the forward and backward of the online network
-  and the forward of the target network over the chip's share of the
-  batch.  Backward counts the weight and input gradients of every layer but
-  the input gradient of the first.
+* the actors' int8 kernels: the kind's ``rollout_kernels`` at each of
+  ``rollout_steps`` per iteration, and its ``push_kernels`` at each
+  parameter push, over the chip's ``local_actors * n_envs``
+  environments;
+* the float operations: the kind's ``learner_flops`` per learner update
+  over the chip's share of the batch, and its ``push_flops`` at each
+  push.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
-Gemm = Tuple[int, int, int]          # (M, K, N) per observation row count
+Gemm = Tuple[int, int, int]          # (M, K, N)
 
 
-def gemms(config: Dict, n_obs: int) -> List[Gemm]:
-    """The GEMMs of one forward pass over ``n_obs`` observations."""
-    pol, env = config["policy"], config["env"]
+def dense_gemms(n_obs: int, d_in: int, widths: Sequence[int]) -> List[Gemm]:
+    """The GEMMs of a chain of dense layers of ``widths`` over ``n_obs``
+    rows of ``d_in`` features."""
     out = []
-    if pol["kind"] == "conv":
-        h, w, c = env["obs_shape"]
-        for f in pol["conv_filters"]:
-            out.append((n_obs * h * w, 9 * c, f))
-            c = f
-        d = h * w * c
-        widths = [pol["fc_width"]]
-    else:
-        d = 1
-        for s in env["obs_shape"]:
-            d *= s
-        widths = list(pol["widths"])
-    for wd in widths + [env["n_actions"]]:
-        out.append((n_obs, d, wd))
-        d = wd
+    for wd in widths:
+        out.append((n_obs, d_in, wd))
+        d_in = wd
     return out
 
 
@@ -65,30 +51,27 @@ def fused_qmlp_bytes(gs: List[Gemm]) -> float:
                  + 4 * m * gs[-1][2])
 
 
-def learner_flops(config: Dict, batch: int) -> float:
-    """GEMM operations of one learner update over ``batch`` rows."""
-    fwd = gemms(config, batch)
+def int8_matmul_calls(gs: List[Gemm]) -> Dict[str, tuple]:
+    """``{"int8_matmul": (ops, bytes, calls)}`` of one call per GEMM."""
+    return {"int8_matmul": (gemm_ops(gs),
+                            sum(int8_matmul_bytes(*g) for g in gs),
+                            len(gs))}
+
+
+def chain_learner_flops(fwd: List[Gemm]) -> float:
+    """One learner update of a chain of GEMMs: the online forward, its
+    backward (weight and input gradients of every GEMM but the input
+    gradient of the first) and the target's forward."""
     m, k, n = fwd[0]
     return 4 * gemm_ops(fwd) - 2 * m * k * n
 
 
-def _add(acc: Dict, kernel: str, ops: float, nbytes: float, calls: int):
-    e = acc.setdefault(kernel, {"ops": 0.0, "bytes": 0.0, "calls": 0})
-    e["ops"] += ops
-    e["bytes"] += nbytes
-    e["calls"] += calls
-
-
-def _actor_forward(acc: Dict, config: Dict, n_obs: int, calls: int,
-                   calibrated: bool):
-    gs = gemms(config, n_obs)
-    if calibrated:
-        _add(acc, "fused_qmlp", calls * gemm_ops(gs),
-             calls * fused_qmlp_bytes(gs), calls)
-    else:
-        for g in gs:
-            _add(acc, "int8_matmul", calls * gemm_ops([g]),
-                 calls * int8_matmul_bytes(*g), calls)
+def _add(acc: Dict, counts: Dict[str, tuple], times: int):
+    for kernel, (ops, nbytes, calls) in counts.items():
+        e = acc.setdefault(kernel, {"ops": 0.0, "bytes": 0.0, "calls": 0})
+        e["ops"] += times * ops
+        e["bytes"] += times * nbytes
+        e["calls"] += times * calls
 
 
 def pushes(first_iter: int, n_iters: int, sync_every: int) -> int:
@@ -99,25 +82,21 @@ def pushes(first_iter: int, n_iters: int, sync_every: int) -> int:
             - first_iter // sync_every)
 
 
-def work(config: Dict, traffic: Dict, chips: int, n_iters: int,
+def work(policy, traffic: Dict, chips: int, n_iters: int,
          n_pushes: int) -> Dict:
     """One chip's work in ``n_iters`` iterations holding ``n_pushes``
-    parameter pushes (see the module docstring)."""
-    t = traffic
+    parameter pushes (see the module docstring); ``policy`` is the
+    configuration bound to its kind (``kinds.Policy``)."""
+    t, kind, config = traffic, policy.kind, policy.config
     local_obs = t["num_actors"] // chips * t["n_envs"]
-    calibrated = (config["policy"]["kind"] == "mlp"
-                  and config["calib_batch"] > 0)
     kernels: Dict = {}
     steps = n_iters * t["rollout_steps"]
-    _actor_forward(kernels, config, local_obs, steps, calibrated)
-    _actor_forward(kernels, config, local_obs, n_pushes, calibrated)
-    if calibrated:
-        _actor_forward(kernels, config, config["calib_batch"], n_pushes,
-                       False)
+    _add(kernels, kind.rollout_kernels(config, local_obs), steps)
+    _add(kernels, kind.push_kernels(config, local_obs), n_pushes)
     updates = n_iters * t["updates_per_iter"]
     per_chip_batch = t["batch_size"] // chips
-    learner = (updates * learner_flops(config, per_chip_batch)
-               + n_pushes * gemm_ops(gemms(config, local_obs)))
+    learner = (updates * kind.learner_flops(config, per_chip_batch)
+               + n_pushes * kind.push_flops(config, local_obs))
     return {
         "kernels": kernels,
         "actor_int8_ops": sum(e["ops"] for e in kernels.values()),

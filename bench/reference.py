@@ -1,18 +1,15 @@
-"""Plain reference of the benchmarked DQN learner and of the actor's head.
+"""Plain reference of the benchmarked DQN learner.
 
-Written from the published description (Mnih et al. 2015 DQN; QuaRL
-arXiv:1910.01055 Table 10 conv policies and Table 5 MLP policies) in
+Written from the published description (Mnih et al. 2015 DQN) in
 straightforward ``jax.numpy``.  It imports nothing of the program under
-test and takes nothing the program made: the weights come from
-``init_weights`` and the benchmark's seed, the transitions are the rows the
-learner was fed.
+test and takes nothing the program made: the weights come from the
+policy kind's ``init_weights`` and the benchmark's seed, the transitions
+are the rows the learner was fed.
 
-The network is a list of layers, each ``{"w": ..., "b": ...}``:
-
-* ``conv``: 3x3, stride 1, SAME padding, NHWC activations, HWIO weights,
-  ReLU;
-* ``dense``: ``x @ w + b``, ReLU on every layer but the last;
-* the conv stack is flattened in (H, W, C) order before the first dense.
+The network is the policy kind's (``policies/<kind>.py``): every function
+here takes its ``forward(weights, obs)``, Q-values ``(B, n_actions)``, and
+works on its weights as a pytree.  ``init_layers`` and ``dense_chain`` are
+plain layers that kinds may build on.
 
 The learner step is the one the configuration states: Huber TD loss
 (delta 1) against a target network, mean over the batch; gradients
@@ -28,70 +25,34 @@ optimizer state all in bfloat16.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, NamedTuple, Sequence
+from typing import Callable, Dict, List, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
-class Layer(NamedTuple):
-    """One layer of the policy: its kind and weight shape."""
-
-    kind: str            # "conv" or "dense"
-    shape: tuple         # HWIO for conv, (in, out) for dense
-
-
-def layers_of(config: Dict) -> List[Layer]:
-    """The layer list of a configuration file's ``policy`` block."""
-    pol = config["policy"]
-    obs = tuple(config["env"]["obs_shape"])
-    out: List[Layer] = []
-    if pol["kind"] == "conv":
-        h, w, c = obs
-        for f in pol["conv_filters"]:
-            out.append(Layer("conv", (3, 3, c, f)))
-            c = f
-        d = h * w * c
-        widths = [pol["fc_width"]]
-    else:
-        d = int(np.prod(obs))
-        widths = list(pol["widths"])
-    for wd in widths:
-        out.append(Layer("dense", (d, wd)))
-        d = wd
-    out.append(Layer("dense", (d, config["env"]["n_actions"])))
-    return out
-
-
-def init_weights(key, layers: Sequence[Layer], dtype=jnp.float32):
-    """N(0, 1/fan_in) weights, zero biases, one key per layer."""
-    keys = jax.random.split(key, len(layers))
+def init_layers(key, shapes: Sequence[tuple], dtype=jnp.float32) -> List:
+    """A list of layers ``{"w": ..., "b": ...}`` of the weight ``shapes``:
+    N(0, 1/fan_in) weights, zero biases, one key per layer."""
+    keys = jax.random.split(key, len(shapes))
     out = []
-    for k, layer in zip(keys, layers):
-        fan_in = int(np.prod(layer.shape[:-1]))
-        w = jax.random.normal(k, layer.shape, jnp.float32) / np.sqrt(fan_in)
+    for k, shape in zip(keys, shapes):
+        fan_in = int(np.prod(shape[:-1]))
+        w = jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)
         out.append({"w": w.astype(dtype),
-                    "b": jnp.zeros((layer.shape[-1],), dtype)})
+                    "b": jnp.zeros((shape[-1],), dtype)})
     return out
 
 
-def forward(weights, layers: Sequence[Layer], obs):
-    """Q-values ``(B, n_actions)`` for observations ``(B, *obs_shape)``."""
-    x = obs
+def dense_chain(layers: Sequence[Dict], x):
+    """``x @ w + b`` through ``layers``, ReLU on every layer but the
+    last; ``x`` is flattened to ``(B, -1)`` before each."""
     last = len(layers) - 1
-    for i, (p, layer) in enumerate(zip(weights, layers)):
-        if layer.kind == "conv":
-            x = jax.lax.conv_general_dilated(
-                x, p["w"], (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"]
+    for i, p in enumerate(layers):
+        x = x.reshape(x.shape[0], -1) @ p["w"] + p["b"]
+        if i < last:
             x = jax.nn.relu(x)
-            if i + 1 < len(layers) and layers[i + 1].kind == "dense":
-                x = x.reshape(x.shape[0], -1)
-        else:
-            x = x.reshape(x.shape[0], -1) @ p["w"] + p["b"]
-            if i < last:
-                x = jax.nn.relu(x)
     return x
 
 
@@ -120,16 +81,16 @@ def learner_init(weights) -> LearnerState:
                         jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
 
 
-def make_step(layers: Sequence[Layer], hp: Dict, dtype):
+def make_step(forward: Callable, hp: Dict, dtype):
     """``step(state, batch, replay_size) -> (state, loss)``, jitted."""
     lr, gamma = hp["lr"], hp["gamma"]
     b1, b2, eps, clip = hp["b1"], hp["b2"], hp["eps"], hp["grad_clip"]
 
     def loss_fn(params, target, batch):
         obs = batch["obs"].astype(dtype)
-        q = forward(params, layers, obs)
+        q = forward(params, obs)
         q_sel = jnp.take_along_axis(q, batch["action"][:, None], axis=1)[:, 0]
-        q_next = forward(target, layers, batch["next_obs"].astype(dtype))
+        q_next = forward(target, batch["next_obs"].astype(dtype))
         y = batch["reward"].astype(dtype) + gamma * (
             1 - batch["done"].astype(dtype)) * jnp.max(q_next, axis=-1)
         return jnp.mean(huber(q_sel - jax.lax.stop_gradient(y)))
@@ -166,11 +127,11 @@ def make_step(layers: Sequence[Layer], hp: Dict, dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_forward(layers: tuple):
-    return jax.jit(functools.partial(forward, layers=layers))
+def _jitted_forward(forward: Callable):
+    return jax.jit(forward)
 
 
-def follow(layers: Sequence[Layer], hp: Dict, weights, batches, sizes,
+def follow(forward: Callable, hp: Dict, weights, batches, sizes,
            dtype=jnp.float32, keep_after: int = 0):
     """Run the learner over ``batches`` (dicts of host arrays, one per
     update) with the replay sizes the program saw.  Returns
@@ -180,7 +141,7 @@ def follow(layers: Sequence[Layer], hp: Dict, weights, batches, sizes,
     with jax.default_matmul_precision(precision):
         w = jax.tree_util.tree_map(lambda x: x.astype(dtype), weights)
         st = learner_init(w)
-        step = make_step(tuple(layers), hp, dtype)
+        step = make_step(forward, hp, dtype)
         losses, kept = [], None
         for i, (batch, size) in enumerate(zip(batches, sizes)):
             st, loss = step(st, batch, jnp.int32(size))
@@ -190,11 +151,11 @@ def follow(layers: Sequence[Layer], hp: Dict, weights, batches, sizes,
         return np.asarray(jnp.stack(losses), np.float64), st, kept
 
 
-def q_values(layers: Sequence[Layer], weights, obs, block: int = 512):
+def q_values(forward: Callable, weights, obs, block: int = 512):
     """Reference Q-values at ``highest`` precision, in blocks of rows."""
-    fwd = _jitted_forward(tuple(layers))
+    fwd = _jitted_forward(forward)
     out = []
     with jax.default_matmul_precision("highest"):
         for i in range(0, obs.shape[0], block):
-            out.append(np.asarray(fwd(weights, obs=obs[i:i + block])))
+            out.append(np.asarray(fwd(weights, obs[i:i + block])))
     return np.concatenate(out)

@@ -4,9 +4,11 @@
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); its correctness limits are
-``bench/limits/<workload>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.  Nothing here names a cell.
+(``bench/traffic/<traffic>.json``); the configuration's ``policy.kind``
+names its policy kind (``bench/policies/<kind>.py``, ``kinds.py``); its
+correctness limits are ``bench/limits/<workload>.json`` and each
+per-layer metric is read by ``bench/metrics/<metric>.py``.  Nothing here
+names a cell or a kind.
 
 A run:
 
@@ -19,11 +21,14 @@ A run:
    correctness check needs (``check.collect``); last, it collects Python's
    garbage and freezes what is left, so that no collection inside the
    window walks the heap that set-up built;
-3. dispatches that chunk back to back for ``--seconds`` seconds, blocking
-   on each chunk's outputs, and counts every chunk completed; the window
-   ends when the first chunk completes past ``--seconds``.  A compilation
-   inside the window is an error.  With ``--trace 1`` the window runs
-   under the profiler and the per-layer metrics are read from its trace;
+3. dispatches that chunk back to back for ``--seconds`` seconds, keeping
+   about ``AHEAD_S`` seconds of chunks queued on the device behind the one
+   it waits for, so that a host that stands still leaves the chip fed;
+   once ``--seconds`` have passed it dispatches nothing more, waits for
+   every chunk it dispatched, and the window ends at that wait: all of
+   their work counts, over all of that time.  A compilation inside the
+   window is an error.  With ``--trace 1`` the window runs under the
+   profiler and the per-layer metrics are read from its trace;
 4. reads the peak device memory, frees the program's state, and runs the
    reference over what set-up read (``check.judge``).
 
@@ -37,6 +42,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
@@ -57,6 +63,8 @@ COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
 
 
 WARMUP_CALLS = 4
+# device seconds of chunks kept queued behind the one the window waits for
+AHEAD_S = 4.0
 
 
 class BenchError(Exception):
@@ -73,8 +81,12 @@ def _load(*parts):
 
 def load_cell(name: str, bench_path: str = None, data_dir: str = HERE
               ) -> dict:
-    """The cell's entry, configuration, traffic, limits and metrics, from
-    ``BENCHMARK.json`` and the files under ``data_dir``."""
+    """The cell's entry, configuration (and, as ``policy``, the
+    configuration bound to its kind), traffic, limits and metrics, from
+    ``BENCHMARK.json`` and the files under ``data_dir``; a kind is looked
+    up under ``data_dir/policies`` before ``bench/policies``."""
+    import kinds
+
     bench = _load(bench_path or os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -84,9 +96,15 @@ def load_cell(name: str, bench_path: str = None, data_dir: str = HERE
     def applies(metric):
         return name in metric.get("workloads", [name])
 
+    config = _load(data_dir, "configs", f"{cell['config']}.json")
+    try:
+        policy = kinds.bind(config, data_dir)
+    except kinds.KindError as e:
+        raise BenchError(str(e)) from e
     return {
         "cell": cell,
-        "config": _load(data_dir, "configs", f"{cell['config']}.json"),
+        "config": config,
+        "policy": policy,
         "traffic": _load(data_dir, "traffic", f"{cell['traffic']}.json"),
         "limits": _load(data_dir, "limits", f"{name}.json"),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
@@ -209,7 +227,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     from drive_actor_learner import ActorLearnerDriver, replicas_equal
 
     spec = load_cell(name, bench_path, data_dir)
-    cell, config, traffic = spec["cell"], spec["config"], spec["traffic"]
+    cell, policy, traffic = spec["cell"], spec["policy"], spec["traffic"]
     chips = cell["chips"]
     if devices is None:
         devices, peaks = find_devices(chips)
@@ -220,24 +238,29 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         mesh = jax.make_mesh((chips,), ("actor",), devices=devices)
 
     # -- set-up -------------------------------------------------------------
-    drv = ActorLearnerDriver(config, traffic, mesh)
+    drv = ActorLearnerDriver(policy, traffic, mesh)
     (state, env_state, obs, key), stash = check.first_iterations(
-        drv, check.weights_fn_for(config), seed, traffic, key_of)
+        drv, seed, traffic, key_of)
     iters_before = traffic["check_iters"]
     # a mesh compiles the chunk again for the shardings its outputs carry:
     # run it until one call compiles nothing
     for _ in range(WARMUP_CALLS):
         iters_before += traffic["steps_per_call"]
         before = counter.count
+        t_call = time.perf_counter()
         state, env_state, obs, key, metrics = drv.chunk(state, env_state,
                                                         obs, key)
         jax.block_until_ready(metrics)
+        chunk_s = time.perf_counter() - t_call
         if counter.count == before:
             break
     else:
         raise BenchError(f"the chunk still compiles after {WARMUP_CALLS} "
                          f"warm-up calls")
 
+    # chunks queued behind the one waited for: the last warm-up call,
+    # which compiled nothing, times one chunk
+    ahead = max(1, int(AHEAD_S // chunk_s))
     gc.collect()
     gc.freeze()
 
@@ -251,21 +274,32 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     setup_s = t0 - T_START
     n_chunks = 0
+    pending = collections.deque()
+
+    def wait_oldest():
+        t_wait = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.wait"):
+            jax.block_until_ready(pending.popleft())
+        t_done = time.perf_counter()
+        spans.append(("bench.wait", t_wait - t0, t_done - t0))
+        return t_done
+
     try:
         while True:
             t_call = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.dispatch"):
                 state, env_state, obs, key, metrics = drv.chunk(
                     state, env_state, obs, key)
-            t_ret = time.perf_counter()
-            with jax.profiler.TraceAnnotation("bench.wait"):
-                jax.block_until_ready(metrics)
-            t_done = time.perf_counter()
-            spans.append(("bench.dispatch", t_call - t0, t_ret - t0))
-            spans.append(("bench.wait", t_ret - t0, t_done - t0))
+            spans.append(("bench.dispatch", t_call - t0,
+                          time.perf_counter() - t0))
+            pending.append(metrics)
             n_chunks += 1
-            if t_done - t0 >= seconds:
+            if len(pending) > ahead:
+                wait_oldest()
+            if time.perf_counter() - t0 >= seconds:
                 break
+        while pending:
+            t_done = wait_oldest()
         window_s = t_done - t0
     finally:
         pauses.in_window = False
@@ -284,7 +318,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     del state, env_state, obs, metrics
 
     n_iters = n_chunks * traffic["steps_per_call"]
-    work = opcount.work(config, traffic, chips, n_iters, opcount.pushes(
+    work = opcount.work(policy, traffic, chips, n_iters, opcount.pushes(
         iters_before, n_iters, traffic["sync_every"]))
     result_metrics = {}
     breakdown = None
@@ -292,7 +326,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               "count": len(devices), "memory_peak_bytes": int(mem)}
     if trace:
         import trace_reduce
-        red = trace_reduce.reduce_dir(trace_dir, n_devices=len(devices))
+        red = trace_reduce.reduce_dir(trace_dir, len(devices),
+                                      tuple(work["kernels"]))
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = {"trace": red, "spans": spans, "work": work, "chips": chips,
                "peaks": peaks}
@@ -315,7 +350,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                                          "unit": m["unit"]}
 
     # -- correctness --------------------------------------------------------
-    numbers = check.judge(stash, config, traffic, key_of(seed, 0))
+    numbers = check.judge(stash, policy, traffic, key_of(seed, 0))
     if "replica_gap" in stash:
         numbers["replica_gap"] = stash["replica_gap"]
     limits = spec["limits"]
@@ -326,6 +361,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         out["breakdown"] = breakdown
     out["checks"] = {k: {"value": numbers[k], "limit": limits[k]}
                      for k in limits}
+    print(f"window: {n_chunks} chunks in {window_s:.4f} s, {ahead} queued "
+          f"behind the one waited for", file=sys.stderr)
     print(pauses.line(), file=sys.stderr)
     return out
 

@@ -12,7 +12,6 @@ import pytest
 
 import check
 import control
-import reference as ref
 import run
 from drive_actor_learner import ActorLearnerDriver
 
@@ -24,15 +23,15 @@ def readings():
     """One seed's readings of the small conv cell, and its int4 control."""
     spec = run.load_cell("conv.tiny", os.path.join(DATA, "BENCHMARK.json"),
                          DATA)
-    drv = ActorLearnerDriver(spec["config"], spec["traffic"])
+    drv = ActorLearnerDriver(spec["policy"], spec["traffic"])
     out = control._readings(drv, spec, 11, 1)
-    cfg4 = dict(spec["config"], actor_backend="int4")
-    drv4 = ActorLearnerDriver(cfg4, spec["traffic"])
-    _, stash = check.first_iterations(drv4, check.weights_fn_for(cfg4), 11,
-                                      spec["traffic"], run.key_of)
-    w0 = check.seed_weights(cfg4, run.key_of(11, 0))
-    refr = check.reference_run(stash, cfg4, spec["traffic"], w0)
-    out["control_int4"] = check.actor_numbers(ref.layers_of(cfg4), w0,
+    pol4 = spec["policy"].with_config(actor_backend="int4")
+    drv4 = ActorLearnerDriver(pol4, spec["traffic"])
+    _, stash = check.first_iterations(drv4, 11, spec["traffic"],
+                                      run.key_of)
+    w0 = check.seed_weights(pol4, run.key_of(11, 0))
+    refr = check.reference_run(stash, pol4, spec["traffic"], w0)
+    out["control_int4"] = check.actor_numbers(pol4.forward, w0,
                                               refr["pushed"], stash)
     return spec["limits"], out
 

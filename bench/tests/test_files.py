@@ -8,8 +8,9 @@ import sys
 
 import pytest
 
+import kinds
 import run
-from drive_actor_learner import _program_widths
+from drive_actor_learner import network_kwargs
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -41,7 +42,7 @@ def test_cell_files_load(cell):
     """A cell's configuration, traffic, limits and readers load."""
     spec = run.load_cell(cell)
     assert spec["config"]["name"] == spec["cell"]["config"]
-    assert _program_widths(spec["config"]["policy"])
+    assert network_kwargs(spec["policy"])
     assert spec["limits"], "a cell compares at least one number"
     assert any(m["name"] == "setup_s" for m in spec["end_to_end"])
     for m in spec["per_layer"]:
@@ -65,6 +66,62 @@ def test_per_layer_metrics_list_cells_that_report_what_they_move():
         for cell in m.get("workloads", CELLS):
             assert cell in CELLS
             assert cell in moved.get("workloads", CELLS)
+
+
+SEQ_DATA = os.path.join(BENCH, "tests", "data", "seq")
+CONFIG_FILES = [(os.path.join(ROOT, c["file"]), kinds.HERE)
+                for c in SPEC["configs"]] + [
+    (os.path.join(SEQ_DATA, "configs", "seq_tiny.json"), SEQ_DATA)]
+
+
+@pytest.mark.parametrize("path,data_dir", CONFIG_FILES,
+                         ids=lambda p: os.path.basename(p))
+def test_kind_file_exports_the_contract(path, data_dir):
+    """Every configuration's kind file loads, exports the contract, and
+    maps its weights to the program's tree and back leaf for leaf."""
+    import jax
+
+    with open(path) as f:
+        config = json.load(f)
+    kind = kinds.load(config["policy"]["kind"], data_dir)
+    assert all(callable(getattr(kind, name)) for name in kinds.CONTRACT)
+    w = jax.eval_shape(lambda k: kind.init_weights(k, config),
+                       jax.random.PRNGKey(0))
+    back = kind.from_program(kind.to_program(w, config), config)
+    assert (jax.tree_util.tree_structure(back)
+            == jax.tree_util.tree_structure(w))
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(back),
+                                      jax.tree_util.tree_leaves(w)))
+
+
+def test_a_data_directory_brings_its_own_kind(tmp_path):
+    """A kind under the data directory is found before the benchmark's;
+    one that is missing, or exports less than the contract, is an
+    error."""
+    (tmp_path / "policies").mkdir()
+    with open(os.path.join(BENCH, "policies", "mlp.py")) as f:
+        body = f.read()
+    (tmp_path / "policies" / "mlp.py").write_text(body + "\nOWN = True\n")
+    assert kinds.load("mlp", str(tmp_path)).OWN
+    assert not hasattr(kinds.load("mlp"), "OWN")
+    (tmp_path / "policies" / "half.py").write_text(
+        "def forward(weights, config, obs):\n    return obs\n")
+    with pytest.raises(kinds.KindError, match="does not export"):
+        kinds.load("half", str(tmp_path))
+    with pytest.raises(kinds.KindError, match="no policies/none.py"):
+        kinds.load("none", str(tmp_path))
+
+
+def test_harness_names_no_kind_and_no_fixed_kernel_list():
+    """Outside the kind files, the harness names no policy kind and no
+    fixed list of kernels."""
+    for name in sorted(os.listdir(BENCH)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(BENCH, name)) as f:
+            text = f.read()
+        for word in ('"conv"', '"mlp"', "kind ==", "KERNELS = ("):
+            assert word not in text, (name, word)
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -17,6 +17,7 @@ import run
 from drive_actor_learner import ActorLearnerDriver
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+SEQ_DATA = os.path.join(DATA, "seq")
 FIELDS = ("obs", "action", "reward", "done", "next_obs")
 
 
@@ -27,8 +28,11 @@ def _rows(fields):
                             for f in FIELDS) for i in range(n))
 
 
-@pytest.mark.parametrize("name", ["conv.tiny", "mlp.tiny", "conv.tiny.x4"])
-def test_sampled_rows_are_the_learner_batches(monkeypatch, name):
+@pytest.mark.parametrize("name,data", [("conv.tiny", DATA),
+                                       ("mlp.tiny", DATA),
+                                       ("conv.tiny.x4", DATA),
+                                       ("seq.tiny", SEQ_DATA)])
+def test_sampled_rows_are_the_learner_batches(monkeypatch, name, data):
     """Each update's re-derived rows are the batch the TD update got."""
     from repro.rl import dqn
     seen = {}
@@ -49,13 +53,12 @@ def test_sampled_rows_are_the_learner_batches(monkeypatch, name):
         return recording
 
     monkeypatch.setattr(dqn, "make_td_update", make)
-    spec = run.load_cell(name, os.path.join(DATA, "BENCHMARK.json"), DATA)
+    spec = run.load_cell(name, os.path.join(data, "BENCHMARK.json"), data)
     chips = spec["cell"]["chips"]
     mesh = jax.make_mesh((chips,), ("actor",),
                          devices=jax.devices()[:chips]) if chips > 1 else None
-    drv = ActorLearnerDriver(spec["config"], spec["traffic"], mesh)
-    _, stash = check.first_iterations(drv, check.weights_fn_for(
-        spec["config"]), 7, spec["traffic"], run.key_of)
+    drv = ActorLearnerDriver(spec["policy"], spec["traffic"], mesh)
+    _, stash = check.first_iterations(drv, 7, spec["traffic"], run.key_of)
     jax.effects_barrier()
     assert len(seen) == len(stash["batches"])
     for u, derived in enumerate(stash["batches"]):

@@ -13,6 +13,8 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 RECORDED = sorted(glob.glob(os.path.join(DATA, "trace_*", "**",
                                          "*.xplane.pb"), recursive=True))
 SCOPED = os.path.join(DATA, "phases_tpu_1chip", "tiny.xplane.pb")
+# the int8 kernels the recorded cells ran
+KERNELS_RUN = ("int8_matmul", "fused_qmlp")
 
 
 def chips_of(path):
@@ -55,8 +57,9 @@ def test_reader_matches_profile_data(path):
     """``trace_reduce`` reads the same numbers from ``xplane.read`` as from
     ``ProfileData``."""
     n = chips_of(path)
-    assert (trace_reduce.reduce_profile(xplane.read(path), n)
-            == trace_reduce.reduce_profile(trace_reduce.load(path), n))
+    assert (trace_reduce.reduce_profile(xplane.read(path), n, KERNELS_RUN)
+            == trace_reduce.reduce_profile(trace_reduce.load(path), n,
+                                           KERNELS_RUN))
 
 
 @pytest.mark.parametrize("path", RECORDED + [SCOPED])
@@ -67,7 +70,7 @@ def test_reader_finds_tf_op_of_kernels(path):
         for line in p.lines:
             for ev in line.events:
                 name, opcode = trace_reduce.parse_op(ev.name)
-                k = trace_reduce.kernel_of(name, opcode)
+                k = trace_reduce.kernel_of(name, opcode, KERNELS_RUN)
                 if k is not None:
                     assert f"jit({k})" in xplane.tf_op(ev)
                     found.add(k)
@@ -79,7 +82,8 @@ def test_phases_sum_to_busy(path):
     """Every instant of busy time counts once, in one phase."""
     n = chips_of(path)
     red = phases.reduce_phases(xplane.read(path), n)
-    busy = trace_reduce.reduce_profile(trace_reduce.load(path), n)["busy_s"]
+    busy = trace_reduce.reduce_profile(trace_reduce.load(path), n,
+                                       KERNELS_RUN)["busy_s"]
     assert sum(red["phases"].values()) == pytest.approx(busy, rel=1e-3)
     assert red["busy_s"] == pytest.approx(busy, rel=1e-3)
 
@@ -141,7 +145,7 @@ def test_overlap_counts_once_for_the_earlier_op():
     assert red["phases"]["learner_update"] == pytest.approx(100e-9)
     assert red["phases"]["other"] == pytest.approx(100e-9)
     assert red["busy_s"] == pytest.approx(
-        trace_reduce.reduce_profile(sp, 1)["busy_s"])
+        trace_reduce.reduce_profile(sp, 1, KERNELS_RUN)["busy_s"])
     assert phases.phase_ms(red, 2)["env_step"] == pytest.approx(1e-4)
 
 
@@ -228,8 +232,10 @@ def test_trim_keeps_what_the_reductions_read(path, tmp_path):
     out = str(tmp_path / "trimmed.xplane.pb")
     xplane.trim(path, out)
     assert os.path.getsize(out) < os.path.getsize(path)
-    full = trace_reduce.reduce_profile(trace_reduce.load(path), n)
-    cut = trace_reduce.reduce_profile(trace_reduce.load(out), n)
+    full = trace_reduce.reduce_profile(trace_reduce.load(path), n,
+                                       KERNELS_RUN)
+    cut = trace_reduce.reduce_profile(trace_reduce.load(out), n,
+                                      KERNELS_RUN)
     for k in ("busy_s", "window_s", "kernels", "collective_s",
               "idle_by_span"):
         assert cut[k] == full[k], k

@@ -19,8 +19,8 @@ writes its HLO text to FILE without what names and debug info change
 (``program_text``) and adds its sha256 to the line: two checkouts whose
 chunks differ only in op metadata write the same text.
 ``--bench`` and ``--data`` name another ``BENCHMARK.json`` and the
-directory of its configuration, traffic and limits files (the tests'
-tiny cells in ``tests/data``).
+directory of its configuration, traffic, limits and kind files (the
+tests' tiny cells in ``tests/data``).
 """
 from __future__ import annotations
 
@@ -73,15 +73,12 @@ def chunk_hlo(spec) -> str:
     """The cell's compiled chunk as HLO text, on one chip."""
     import jax
 
-    import check
     from drive_actor_learner import ActorLearnerDriver
     if spec["cell"]["chips"] != 1:
         raise run.BenchError("--hlo compiles one-chip cells only")
-    drv = ActorLearnerDriver(spec["config"], spec["traffic"])
-    weights_fn = check.weights_fn_for(spec["config"])
+    drv = ActorLearnerDriver(spec["policy"], spec["traffic"])
     keys = [run.key_of(0, s) for s in range(4)]
-    carry = jax.eval_shape(
-        lambda a, b, c: drv.init(weights_fn, a, b, c), *keys[:3])
+    carry = jax.eval_shape(drv.init, *keys[:3])
     return drv.chunk.lower(*carry, keys[3]).compile().as_text()
 
 
@@ -103,7 +100,7 @@ def main(argv=None) -> int:
     read = {}
     reduce_dir = trace_reduce.reduce_dir
 
-    def reduce_and_read(path, n_devices):
+    def reduce_and_read(path, n_devices, kernels):
         space = xplane.read(path)
         read["phases"] = phases.reduce_phases(space, n_devices)
         read["gaps"] = phases.idle_gap_events(space, n_devices)
@@ -111,7 +108,7 @@ def main(argv=None) -> int:
             os.makedirs(args.keep, exist_ok=True)
             xplane.trim(path, os.path.join(args.keep,
                                            f"{args.workload}.xplane.pb"))
-        return reduce_dir(path, n_devices)
+        return reduce_dir(path, n_devices, kernels)
 
     try:
         spec = run.load_cell(args.workload, args.bench, args.data)

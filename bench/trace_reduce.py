@@ -14,9 +14,10 @@ clock.
 * ``busy_s``: the union of the device's operation intervals inside it;
 * ``ops``: device seconds by operation (the first 160 characters of its
   HLO text: name, shape, opcode and first operands);
-* ``kernels``: device seconds of the int8 Pallas kernels, by kernel: a
-  ``custom-call`` whose name carries the kernel's (``int8_matmul.36``,
-  ``vmap_jit_fused_qmlp__.1``);
+* ``kernels``: device seconds of the int8 Pallas kernels the cell's work
+  names (the keys of ``opcount.work``'s ``kernels``, which the policy
+  kind counts), by kernel: a ``custom-call`` whose name carries the
+  kernel's (``int8_matmul.36``, ``vmap_jit_fused_qmlp__.1``);
 * ``collective_s``: device seconds of all-reduce, all-gather,
   reduce-scatter, all-to-all and collective-permute operations;
 * ``idle_by_span`` and ``longest_gaps``: the idle intervals inside the
@@ -35,13 +36,12 @@ import glob
 import os
 import re
 import sys
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 WINDOW_SPANS = ("bench.dispatch", "bench.wait")
 HOST_SPANS = WINDOW_SPANS + ("python.gc",)
-KERNELS = ("int8_matmul", "fused_qmlp")
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
 CONTROL_FLOW = ("while", "conditional", "call")
@@ -79,11 +79,12 @@ def parse_op(hlo: str) -> Tuple[str, str]:
     return name.lstrip("%"), (m.group(1) if m else "")
 
 
-def kernel_of(name: str, opcode: str):
-    """The int8 kernel an operation runs, or None."""
+def kernel_of(name: str, opcode: str, kernels: Sequence[str]):
+    """The first of ``kernels`` whose name an operation's carries, where
+    the operation is a ``custom-call``; else None."""
     if opcode != "custom-call":
         return None
-    for k in KERNELS:
+    for k in kernels:
         if k in name:
             return k
     return None
@@ -115,8 +116,10 @@ def host_spans(profile) -> List[Tuple[str, int, int]]:
     return sorted(out, key=lambda s: s[1])
 
 
-def reduce_profile(profile, n_devices: int) -> Dict:
-    """The numbers of the module docstring, from a loaded profile."""
+def reduce_profile(profile, n_devices: int, kernels: Sequence[str]
+                   ) -> Dict:
+    """The numbers of the module docstring, from a loaded profile;
+    ``kernels`` are the names of the kernels to count."""
     spans = host_spans(profile)
     planes = device_planes(profile, n_devices)
     if not planes:
@@ -131,7 +134,7 @@ def reduce_profile(profile, n_devices: int) -> Dict:
     n = len(planes)
     busy_ns, coll_ns = 0, 0
     ops: Dict[str, float] = {}
-    kernels: Dict[str, float] = {}
+    by_kernel: Dict[str, float] = {}
     gaps: Dict[str, float] = {}
     longest: List[Tuple[float, str]] = []
     for p in planes:
@@ -148,9 +151,9 @@ def reduce_profile(profile, n_devices: int) -> Dict:
                 dur = (e - s) / n
                 label = ev.name[:LABEL_CHARS]
                 ops[label] = ops.get(label, 0.0) + dur
-                k = kernel_of(name, opcode)
+                k = kernel_of(name, opcode, kernels)
                 if k is not None:
-                    kernels[k] = kernels.get(k, 0.0) + dur
+                    by_kernel[k] = by_kernel.get(k, 0.0) + dur
                 if is_collective(opcode):
                     coll_ns += dur
         busy = _union(ivs)
@@ -169,16 +172,16 @@ def reduce_profile(profile, n_devices: int) -> Dict:
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_ns * 1e-9,
         "ops": {k: v * 1e-9 for k, v in ops.items()},
-        "kernels": {k: v * 1e-9 for k, v in kernels.items()},
+        "kernels": {k: v * 1e-9 for k, v in by_kernel.items()},
         "collective_s": coll_ns * 1e-9,
         "idle_by_span": {k: v * 1e-9 for k, v in gaps.items()},
         "longest_gaps": [(lbl, s * 1e-9) for s, lbl in longest[:10]],
     }
 
 
-def reduce_dir(path: str, n_devices: int) -> Dict:
+def reduce_dir(path: str, n_devices: int, kernels: Sequence[str]) -> Dict:
     """``reduce_profile`` of the trace under ``path``."""
-    return reduce_profile(load(path), n_devices)
+    return reduce_profile(load(path), n_devices, kernels)
 
 
 def breakdown(red: Dict) -> Dict:
